@@ -87,7 +87,10 @@ def spec_args(exp) -> tuple[list[str], dict[str, str]]:
     for key, value in dict(extras.get("overrides", {})).items():
         args += ["--set", f"{key}={value}"]
     n_dev = int(extras.get("xla_devices", 512))
-    env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_dev}"}
+    # The sweep lowers for placeholder host devices: the child stays off
+    # any accelerator (the parent, or another process, may hold it).
+    env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_dev}",
+           "JAX_PLATFORMS": "cpu"}
     return args, env
 
 

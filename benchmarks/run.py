@@ -230,6 +230,8 @@ def main() -> None:
                          "(default experiment_<name>.json)")
     args = ap.parse_args()
     quick = not args.full
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     benches = _import_benchmarks()
 

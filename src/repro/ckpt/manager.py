@@ -20,9 +20,9 @@ bytes ``s``, C = s / bw (each chip writes its own shard concurrently — the
 coordinated-checkpointing cost is per-shard, not global).  ``measure=True``
 instead times the actual host serialization, for CPU-scale examples.
 
-The quantize/dequantize hot loop is the Pallas ``ckpt_delta`` kernel
-(``repro.kernels.ckpt_delta``); the manager falls back to the pure-jnp
-reference on CPU.
+The quantize/dequantize hot loop has a Pallas kernel
+(``repro.kernels.ckpt_delta``); the manager calls its pure-jnp reference on
+every platform.
 """
 
 from __future__ import annotations

@@ -39,6 +39,7 @@ import dataclasses
 import hashlib
 import json
 import math
+import multiprocessing
 import os
 import pickle
 import sys
@@ -432,6 +433,12 @@ def _eval_chunk(trace: EventTrace, platform: Platform, time_base: float,
             for slot, strat in items]
 
 
+def _worker_init() -> None:
+    """Scalar-fallback workers simulate in numpy only; should anything in
+    them import JAX, it must not claim the accelerator the parent holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
+
 def _resolve_workers(workers: int | None) -> int:
     """Worker count for the scalar-fallback pool: explicit argument, then
     ``$REPRO_EXPERIMENT_WORKERS``, then the machine's CPU count."""
@@ -572,7 +579,11 @@ def evaluate_strategies(
                     del by_trace[ti]
     n_scalar = sum(len(items) for items in by_trace.values())
     if workers > 1 and n_scalar >= _MIN_PARALLEL_SIMS:
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        # Spawned, never forked: a forked child would inherit a parent
+        # that may already hold the accelerator.
+        with ProcessPoolExecutor(
+                max_workers=workers, initializer=_worker_init,
+                mp_context=multiprocessing.get_context("spawn")) as pool:
             futures = {
                 ti: pool.submit(_eval_chunk, traces[ti], platform, time_base,
                                 cp, seed, ti, items)

@@ -5,8 +5,11 @@
   ckpt_delta        int8 delta quantization for proactive checkpoints
 
 Each kernel ships with a pure-jnp oracle in ref.py; ops.py is the public
-dispatching API.  Kernels are validated in interpret mode on CPU and are
-TARGETED at TPU (BlockSpec VMEM tiling, MXU-aligned tiles).
+dispatching API.  The kernels target the TPU (BlockSpec VMEM tiling,
+MXU-aligned tiles) and compile by default; interpret mode runs only where a
+caller asks for it (``interpret=True``, ``impl="pallas_interpret"``), as the
+CPU tests do.  ``tests/test_tpu_compile.py`` compiles the checkpoint-delta
+kernels for a described v5e.
 """
 
 from . import ckpt_delta, decode_attention, flash_attention, ops, ref

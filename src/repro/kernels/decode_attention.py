@@ -12,7 +12,8 @@ matmul per kv head.  Tiling:
   * ``length`` (B,) masks the valid cache prefix (ring-buffer semantics for
     sliding-window archs: valid = min(length, window) entries).
 
-Contract: ``ref.decode_attention_ref``; swept in interpret mode by tests.
+Contract: ``ref.decode_attention_ref``; swept in interpret mode by tests
+(``interpret=True``).  The default compiles the kernel for the TPU.
 """
 
 from __future__ import annotations
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 __all__ = ["decode_attention_pallas"]
 
@@ -72,7 +72,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, length: jax.Array, *,
                             window: int = 0, bk: int = 512,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool = False) -> jax.Array:
     """q (B,1,H,hd); caches (B,S,KV,hd); length (B,) -> (B,1,H,hd)."""
     b, _, h, hd = q.shape
     s, kv = k_cache.shape[1], k_cache.shape[2]
@@ -104,7 +104,7 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length.astype(jnp.int32), qr, kr, vr)

@@ -22,11 +22,12 @@ Implementations (the :mod:`repro.kernels.ops` idiom):
     engine jits; XLA fuses it into one elementwise kernel);
   * ``impl="pallas_interpret"`` — the Pallas kernel in interpreter mode
     (CPU; validated against the reference);
-  * ``impl="pallas"`` — the compiled Pallas kernel for TPU runs, built
-    behind the :mod:`repro.kernels.compat` shim.  Note the engine's
-    equivalence contract needs x64, which TPUs lower through float64
-    emulation — the compiled path is the structure for accelerator
-    deployments that relax the contract to float32 tolerances.
+  * ``impl="pallas"`` — the compiled Pallas kernel.  Its tiles carry the
+    engine's float64 state, and Pallas on the TPU has no float64 (XLA only
+    emulates it outside kernels), so the compiled kernel refuses float64
+    input up front (:func:`check_compiled_dtype`) instead of failing inside
+    the Mosaic lowering.  It becomes usable once the lane state has a
+    format the chip runs natively.
 """
 
 from __future__ import annotations
@@ -36,9 +37,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["F_FIELDS", "I_FIELDS", "N_F", "N_I", "event_step",
-           "event_step_ref", "event_step_pallas"]
+           "event_step_ref", "event_step_pallas", "check_compiled_dtype"]
 
 # Phase codes (repro.core.simulator's private constants, frozen here so the
 # kernel module has no engine import cycle).
@@ -262,6 +264,21 @@ def event_step_ref(fs: jax.Array, is_: jax.Array, *, c: float, cp: float,
     return _advance_math(fs, is_, c=c, cp=cp, d=d, r=r, time_base=time_base)
 
 
+def check_compiled_dtype(dtype) -> None:
+    """Refuse float64 tiles for the compiled kernel, before any lowering.
+
+    The lane state's float rows are float64 for the engines' equivalence
+    contract; the TPU's Pallas lowering has no float64 and fails deep in
+    Mosaic (a ``RecursionError``) when handed such tiles.
+    """
+    if jnp.dtype(dtype) == jnp.float64:
+        raise NotImplementedError(
+            "the compiled event-step kernel (REPRO_JAX_PALLAS=1) would carry "
+            "the lane state as float64 (N_F, lanes) tiles, and Pallas on the "
+            "TPU has no float64; use the default jnp step "
+            "(REPRO_JAX_PALLAS unset) or interpret mode")
+
+
 def _event_kernel(fs_ref, is_ref, ofs_ref, ois_ref, *, c, cp, d, r,
                   time_base):
     fs_out, is_out = _advance_math(fs_ref[...], is_ref[...], c=c, cp=cp,
@@ -274,7 +291,7 @@ def _event_kernel(fs_ref, is_ref, ofs_ref, ois_ref, *, c, cp, d, r,
                                              "time_base", "interpret"))
 def event_step_pallas(fs: jax.Array, is_: jax.Array, *, c: float, cp: float,
                       d: float, r: float, time_base: float,
-                      interpret: bool = True
+                      interpret: bool = False
                       ) -> tuple[jax.Array, jax.Array]:
     """Pallas path: 1-D lane grid, one (N_F + N_I, LANE_BLOCK) tile per
     program.  Pads the lane axis to the block size (padding lanes satisfy
@@ -291,8 +308,8 @@ def event_step_pallas(fs: jax.Array, is_: jax.Array, *, c: float, cp: float,
                                time_base=time_base)
     kwargs = {}
     if not interpret:
-        from .compat import CompilerParams
-        kwargs["compiler_params"] = CompilerParams(
+        check_compiled_dtype(fs.dtype)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",))
     ofs, ois = pl.pallas_call(
         kernel,

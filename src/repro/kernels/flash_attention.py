@@ -12,7 +12,8 @@ for the TPU memory hierarchy:
     h // (H // KV) — no head replication in HBM.
 
 The contract is ``ref.flash_attention_ref``; tests sweep (seq, heads, kv,
-window, dtype) in interpret mode.  On real TPUs, set ``interpret=False``.
+window, dtype) in interpret mode, which a caller asks for with
+``interpret=True``.  The default compiles the kernel for the TPU.
 """
 
 from __future__ import annotations
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 __all__ = ["flash_attention_pallas"]
 
@@ -81,7 +81,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            q_offset: int = 0, bq: int = 128, bk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q (B,Sq,H,hd); k,v (B,Skv,KV,hd) -> (B,Sq,H,hd)."""
     b, sq, h, hd = q.shape
     skv, kv = k.shape[1], k.shape[2]
@@ -125,7 +125,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

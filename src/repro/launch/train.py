@@ -3,10 +3,12 @@
 Runs the fault-tolerant trainer on a reduced (CPU) or full (TPU) config:
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
-        --reduced --steps 200 --seq 128 --batch 8 --faults --workdir /tmp/ck
+        --steps 200 --seq 128 --batch 8 --faults --workdir ck
 
-On the CPU container only reduced configs execute numerically; the full
-configs are exercised by the dry-run (repro.launch.dryrun).
+``--reduced`` (the default) trains the config's smoke-size variant, which
+runs on a CPU; ``--no-reduced`` trains it at its published widths, which
+needs an accelerator for all but the smallest configs.  Without
+``--workdir`` the checkpoints go to a new temporary directory.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import tempfile
 
 import numpy as np
 
@@ -21,18 +24,24 @@ from repro.configs import REGISTRY, get
 from repro.configs.base import InputShape, PlatformConfig
 from repro.configs.paper import SYNTHETIC
 from repro.core.traces import Exponential, Weibull, make_event_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import FaultTolerantTrainer
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="tinyllama-1.1b",
                     choices=sorted(REGISTRY))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the smoke-size variant of the config "
+                         "(--no-reduced: its published widths)")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--workdir", default="/tmp/repro_ckpt")
+    ap.add_argument("--workdir", default=None,
+                    help="checkpoint directory (default: a new temporary "
+                         "directory)")
     ap.add_argument("--faults", action="store_true",
                     help="inject faults from a synthetic trace")
     ap.add_argument("--fault-dist", default="exponential",
@@ -43,7 +52,13 @@ def main() -> None:
                     help="virtual seconds per training step")
     ap.add_argument("--no-predictor", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    workdir = args.workdir or tempfile.mkdtemp(prefix="repro_ckpt_")
 
     cfg = get(args.arch)
     if args.reduced:
@@ -64,7 +79,7 @@ def main() -> None:
             rng=np.random.default_rng(args.seed))
 
     trainer = FaultTolerantTrainer(
-        cfg, shape, plat, workdir=args.workdir, step_time=args.step_time,
+        cfg, shape, plat, workdir=workdir, step_time=args.step_time,
         trace=trace, use_predictor=not args.no_predictor, seed=args.seed)
     print(f"arch={cfg.name} period T*={trainer.scheduler.period:.1f}s "
           f"use_pred={trainer.scheduler.decision.use_predictions} "

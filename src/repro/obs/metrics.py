@@ -15,9 +15,10 @@ Metric names used by the instrumented call sites:
 ``runner.cache_hits`` / ``_misses``     eval-cache outcomes (counter)
 ``runner.eval_s``                       strategy-evaluation wall time
 ``jax.chunks``                          lane chunks driven (counter)
-``jax.compile_s``                       first-chunk (compile+run) seconds
-``jax.run_s``                           steady-state chunk seconds
+``jax.compile_s``                       lane-loop compilation seconds
+``jax.run_s``                           lane-loop run seconds, all chunks
 ``jax.lanes_per_s``                     lanes/second of the last call
+``jax.shards``                          devices the last call sharded over
 ``engine.deferred_overflows``           deferred-fault capacity trips
 ``fleet.faults`` / ``fleet.repair_waits``  fleet coupling events
 ``ft.predictions`` / ``ft.faults_injected``  ft-runtime activity

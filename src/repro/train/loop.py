@@ -87,6 +87,7 @@ class FaultTolerantTrainer:
         self.manager = CheckpointManager(workdir,
                                          bandwidth=platform.ckpt_bandwidth)
 
+        self._seed = seed
         params, self.axes = init_params(cfg, jax.random.PRNGKey(seed))
         self.state: dict[str, Any] = {
             "params": params,
@@ -179,7 +180,8 @@ class FaultTolerantTrainer:
         except FileNotFoundError:
             # No checkpoint yet: restart from scratch (step 0 state is
             # reproducible from the seed).
-            params, _ = init_params(self.cfg, jax.random.PRNGKey(0))
+            params, _ = init_params(self.cfg,
+                                    jax.random.PRNGKey(self._seed))
             self.state = {"params": params,
                           "opt": adamw_init(params, self.opt_cfg),
                           "data_step": jnp.zeros((), jnp.int32)}

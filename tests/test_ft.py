@@ -237,3 +237,19 @@ def test_predictor_reduces_measured_waste(tmp_path, fault_trace):
                                    step_time=10.0, trace=fault_trace,
                                    seed=0, use_predictor=False).run(60)
     assert with_pred.waste < without.waste
+
+
+def test_rollback_before_first_checkpoint_restarts_from_seed(tmp_path):
+    """A fault before any checkpoint restarts from the model the trainer
+    was built with, for every seed (not from seed 0)."""
+    from repro.train.loop import TrainerStats
+    tr = FaultTolerantTrainer(CFG, SHAPE, PLAT, workdir=str(tmp_path),
+                              step_time=10.0, seed=3)
+    init = jax.device_get(tr.state["params"])
+    tr._work_since_save = 0.0
+    tr._do_step(TrainerStats())
+    tr._rollback(TrainerStats(), fault_time=tr.clock.now)
+    got = jax.device_get(tr.state["params"])
+    assert int(tr.state["data_step"]) == 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(init)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -190,10 +190,10 @@ def test_event_step_pallas_interpret_matches_ref():
     n = 300
     fs = jnp.asarray(r.uniform(0.0, 5000.0, (N_F, n)))
     is_ = jnp.asarray(
-        np.stack([r.integers(0, 5, n), r.integers(0, 2, n),
-                  r.integers(0, 40, n), r.integers(0, 40, n)]
+        np.stack([r.integers(0, 5, n), r.integers(0, 2, n)]
+                 + [r.integers(0, 40, n) for _ in range(N_I - 2)]
                  ).astype(np.int32))
-    assert is_.shape == (N_I, n)    # phase/finished/periodic/proactive
+    assert is_.shape == (N_I, n)    # phase/finished, then the counters
     kw = dict(c=60.0, cp=30.0, d=10.0, r=30.0, time_base=120000.0)
     f_ref, i_ref = event_step(fs, is_, impl="ref", **kw)
     f_pl, i_pl = event_step(fs, is_, impl="pallas_interpret", **kw)

@@ -171,3 +171,63 @@ def test_best_period_improves_or_matches():
                                   n_points=8, span=4.0)
     assert m_best <= m_base + 1e-6
     assert refined.name == "BestPeriod(RFO)"
+
+
+# -- compile cache and launcher flags ------------------------------------------
+
+@pytest.fixture
+def cache_config():
+    """Restore JAX's compilation-cache directory after the test."""
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_defaults_to_repo_path(monkeypatch, cache_config):
+    from repro.launch.compile_cache import (DEFAULT_CACHE_DIR,
+                                            enable_compile_cache)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = enable_compile_cache()
+    assert path == str(DEFAULT_CACHE_DIR) == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+    with open(os.path.join(root, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, tmp_path, cache_config):
+    from repro.launch.compile_cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compile_cache_entries_land_in_env_dir(tmp_path):
+    """A process that compiles writes its cache entries where
+    JAX_COMPILATION_CACHE_DIR says, and nowhere under the repo."""
+    from repro.launch.compile_cache import DEFAULT_CACHE_DIR
+    before = sorted(os.listdir(DEFAULT_CACHE_DIR)) \
+        if DEFAULT_CACHE_DIR.exists() else None
+    code = ("from repro.launch.compile_cache import enable_compile_cache\n"
+            "enable_compile_cache()\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0)).block_until_ready()\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert os.listdir(tmp_path / "cc")
+    after = sorted(os.listdir(DEFAULT_CACHE_DIR)) \
+        if DEFAULT_CACHE_DIR.exists() else None
+    assert after == before
+
+
+@pytest.mark.parametrize("argv,reduced", [([], True), (["--reduced"], True),
+                                          (["--no-reduced"], False)])
+def test_train_launcher_reduced_is_switchable(argv, reduced):
+    from repro.launch.train import build_parser
+    assert build_parser().parse_args(argv).reduced is reduced

@@ -233,6 +233,7 @@ def test_roofline_spec_args_without_jax():
     argv, env = roofline.spec_args(exp)
     assert "--pairs" in argv
     assert "device_count=512" in env["XLA_FLAGS"]
+    assert env["JAX_PLATFORMS"] == "cpu"
     assert ExperimentSpec.from_json(exp.to_json()) == exp
 
 
