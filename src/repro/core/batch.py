@@ -1091,9 +1091,11 @@ def simulate_lanes(
         raise ValueError("lane array lengths differ")
     if lane_trace.size == 0:
         return np.empty(0, dtype=np.float64)
-    bank = _pack_bank(traces, start)
     if backend == "jax":
+        from ..obs.metrics import get_registry
         from .batch_jax import run_lanes_jax
+        with get_registry().timer("lanes.pack_s"):
+            bank = _pack_bank(traces, start)
         out = run_lanes_jax(bank, platform, time_base, lane_trace,
                             lane_period, lane_kind, lane_param, lane_window,
                             lane_seed, cp, lane_wmode=lane_wmode,
@@ -1104,8 +1106,9 @@ def simulate_lanes(
         return out["makespan"]
     if backend != "numpy":
         raise ValueError(f"unknown backend {backend!r}")
-    st = _run_lanes(bank, platform, time_base, lane_trace, lane_period,
-                    lane_kind, lane_param, lane_window, lane_seed, cp,
+    st = _run_lanes(_pack_bank(traces, start), platform, time_base,
+                    lane_trace, lane_period, lane_kind, lane_param,
+                    lane_window, lane_seed, cp,
                     lane_wmode, lane_wperiod, lane_adaptive,
                     lane_nverify=lane_nv, lane_vcost=lane_vc,
                     lane_keep=lane_kc)

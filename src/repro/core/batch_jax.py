@@ -53,8 +53,8 @@ amortize it.
 
 from __future__ import annotations
 
+import functools
 import os
-import time
 from typing import Any, Sequence
 
 import numpy as np
@@ -122,6 +122,23 @@ def _draw_tables(bank, lane_trace: np.ndarray, lane_kind: np.ndarray,
         n = int(need[i])
         tab[i, :n] = np.random.default_rng(int(lane_seed[i])).random(n)
     return tab
+
+
+@functools.cache
+def _listen_for_cache_hits() -> None:
+    """Count each of JAX's persistent-cache hits into the installed
+    registry (``jax.cache_hits``).  JAX reports a miss only where it writes
+    an entry, and nothing where the cache is off, so a lane-loop compile
+    that no hit came with is counted as a miss (``jax.cache_misses``)."""
+    import jax
+
+    from repro.obs.metrics import get_registry
+
+    def on_event(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            get_registry().count("jax.cache_hits")
+
+    jax.monitoring.register_event_listener(on_event)
 
 
 # Slack of the adaptive re-plan prefilter toward firing (see `_fixup`).
@@ -262,7 +279,12 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
     else:
         ad_estmu = np.zeros(L, dtype=bool)
 
-    tab = _draw_tables(bank, lane_trace, lane_kind, lane_window, lane_seed)
+    from repro.obs.metrics import get_registry
+    reg = get_registry()
+    _listen_for_cache_hits()
+    with reg.timer("jax.draw_tables_s"):
+        tab = _draw_tables(bank, lane_trace, lane_kind, lane_window,
+                           lane_seed)
     TW = tab.shape[1]
 
     # The trace bank enters the loop as an argument (not a closed-over
@@ -641,6 +663,7 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                     overflow=overflow)
 
     def _body(s, kc, bk):
+        s = dict(s, n_iters=s["n_iters"] + ~s["finished"])
         s, tmp = jax.vmap(_pop_one, in_axes=(0, 0, None))(s, kc, bk)
         # In-window fault date, guarded against FMA contraction (see
         # `_pop_one`): the runtime zero (now - now; unfoldable, now could
@@ -673,9 +696,11 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                 lambda v: P("i") if np.ndim(v) == 1 else P("i", None), tree)
 
         bank_specs = jax.tree_util.tree_map(lambda _: P(), bank_arrs)
-        bank_dev = jax.device_put(bank_arrs, NamedSharding(mesh, P()))
+        bank_to = NamedSharding(mesh, P())
     else:
-        bank_dev = jax.device_put(bank_arrs)
+        bank_to = None
+    with reg.timer("jax.bank_put_s"):
+        bank_dev = jax.device_put(bank_arrs, bank_to)
 
     # -- chunk driver --------------------------------------------------------
     def _init_chunk(sl: slice, n_real: int):
@@ -729,6 +754,7 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             "n_silent": np.zeros(n, i4),
             "n_verifications": np.zeros(n, i4),
             "n_deep_rollbacks": np.zeros(n, i4),
+            "n_iters": np.zeros(n, i4),
         }
         state["finished"][n_real:] = True
         kc = {
@@ -772,19 +798,17 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                 "time_ckpt", "time_prockpt", "time_down",
                 "time_lost", "time_downtime", "time_recovery",
                 "n_silent", "n_verifications", "n_deep_rollbacks",
-                "time_verify", "n_replans", "period", "tparam")
+                "time_verify", "n_replans", "period", "tparam", "n_iters")
     ad_keys = ("ad_ntp", "ad_nfp", "ad_nuf", "ad_gs", "ad_gn")
     acc = {k: np.zeros(L, np.float64) for k in out_keys}
     acc.update({k: np.zeros(L, np.float64) for k in ad_keys})
 
-    from repro.obs.metrics import get_registry
-    reg = get_registry()
     reg.gauge("jax.shards", n_shards)
-    wall0 = time.perf_counter()
     for lo in range(0, L, CL):
         n_real = min(CL, L - lo)
         sl = slice(lo, lo + n_real)
-        state, kc = _init_chunk(sl, n_real)
+        with reg.timer("jax.init_chunk_s"):
+            state, kc = _init_chunk(sl, n_real)
         if has_adaptive:
             cfgs = list(lane_adaptive[lo:lo + n_real])
             holder["cfgs"] = cfgs + [None] * (CL - n_real)
@@ -798,13 +822,28 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                     donate_argnums=0)
             else:
                 fn = jax.jit(_loop, donate_argnums=0)
-            t0 = time.perf_counter()
-            run_jit = fn.lower(state, kc, bank_dev).compile()
-            reg.add_time("jax.compile_s", time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        final = jax.device_get(run_jit(state, kc, bank_dev))
-        reg.add_time("jax.run_s", time.perf_counter() - t0)
+            with reg.timer("jax.lower_s") as lower:
+                lowered = fn.lower(state, kc, bank_dev)
+            hits = reg.counters.get("jax.cache_hits", 0)
+            with reg.timer("jax.xla_compile_s") as comp:
+                run_jit = lowered.compile()
+            reg.count("jax.cache_misses",
+                      int(reg.counters.get("jax.cache_hits", 0) == hits))
+            reg.add_time("jax.compile_s", lower.seconds + comp.seconds)
+        with reg.timer("jax.dispatch_s") as dispatch:
+            out = run_jit(state, kc, bank_dev)
+        with reg.timer("jax.fetch_s") as fetch:
+            final = jax.device_get(out)
+        reg.add_time("jax.run_s", dispatch.seconds + fetch.seconds)
         reg.count("jax.chunks")
+        # Each shard's while loop runs a contiguous block of lanes until
+        # its slowest lane finishes; a lane counts the iterations it was
+        # unfinished at.
+        iters = final["n_iters"]
+        loops = iters.reshape(n_shards, -1).max(axis=1)
+        reg.count("jax.loop_iters", int(loops.sum()))
+        reg.count("jax.lane_iters", int(iters[:n_real].sum()))
+        reg.count("jax.lane_slots", int(loops.sum()) * (CL // n_shards))
         if final["overflow"].any():
             reg.count("engine.deferred_overflows")
             raise RuntimeError(
@@ -815,9 +854,6 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         if has_adaptive:
             for key in ad_keys:
                 acc[key][sl] = final[key][:n_real]
-    wall = time.perf_counter() - wall0
-    if wall > 0.0:
-        reg.gauge("jax.lanes_per_s", L / wall)
 
     # -- final-plan / estimator diagnostics (mirrors the NumPy engine) ------
     er = np.full(L, -1.0)
@@ -859,4 +895,5 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         "est_recall": er,
         "est_precision": ep,
         "est_mu": em,
+        "n_iters": acc["n_iters"].astype(np.int64),
     }

@@ -58,6 +58,7 @@ from repro.core.simulator import (AlwaysTrust, FixedProbabilityTrust,
                                   simulate)
 from repro.core.traces import EventTrace
 from repro.core.waste import Platform
+from repro.obs.metrics import get_registry
 
 from .spec import SECONDS_PER_DAY, ExperimentSpec, ScenarioSpec
 
@@ -502,6 +503,7 @@ def evaluate_strategies(
     """
     cache = cache if cache is not None else EvalCache()
     engine = _resolve_engine(engine)
+    reg = get_registry()
     n = len(traces)
     makespans = np.empty((len(strategies), max(1, n)), dtype=np.float64)
 
@@ -510,27 +512,28 @@ def evaluate_strategies(
     lane_items: list[tuple[int, int]] = []        # (si, ti) for the lane engine
     by_trace: dict[int, list[tuple[int, Strategy]]] = {}
     seen_keys: dict[tuple, tuple[int, int]] = {}  # key -> first slot
-    for si, strat in enumerate(strategies):
-        lanes_ok = engine != "scalar" and _batchable(strat)
-        if engine in ("batch", "jax") and not lanes_ok:
-            raise ValueError(
-                f"engine={engine!r} cannot run strategy {strat.name!r} "
-                f"(dynamic period or unsupported trust policy); use "
-                f"engine='auto' to allow the scalar fallback")
-        for ti in range(n):
-            got = cache.get(strat, ti)
-            if got is not None:
-                makespans[si, ti] = got
-                continue
-            key = (_candidate_key(strat), ti)
-            if key in seen_keys:
-                pending.setdefault(key, []).append(si)
-                continue
-            seen_keys[key] = (si, ti)
-            if lanes_ok:
-                lane_items.append((si, ti))
-            else:
-                by_trace.setdefault(ti, []).append((si, strat))
+    with reg.timer("runner.gather_s"):
+        for si, strat in enumerate(strategies):
+            lanes_ok = engine != "scalar" and _batchable(strat)
+            if engine in ("batch", "jax") and not lanes_ok:
+                raise ValueError(
+                    f"engine={engine!r} cannot run strategy {strat.name!r} "
+                    f"(dynamic period or unsupported trust policy); use "
+                    f"engine='auto' to allow the scalar fallback")
+            for ti in range(n):
+                got = cache.get(strat, ti)
+                if got is not None:
+                    makespans[si, ti] = got
+                    continue
+                key = (_candidate_key(strat), ti)
+                if key in seen_keys:
+                    pending.setdefault(key, []).append(si)
+                    continue
+                seen_keys[key] = (si, ti)
+                if lanes_ok:
+                    lane_items.append((si, ti))
+                else:
+                    by_trace.setdefault(ti, []).append((si, strat))
 
     # One lockstep pass over every batchable (candidate, trace) lane.
     if lane_items:
@@ -553,9 +556,10 @@ def evaluate_strategies(
             keep_ckpts=[strategies[si].keep_ckpts for si, _ in lane_items],
             seeds=seed + 7919 * tr_idx,
             backend="jax" if engine == "jax" else "numpy")
-        for (si, ti), m in zip(lane_items, lane_ms):
-            makespans[si, ti] = m
-            cache.put(strategies[si], ti, float(m))
+        with reg.timer("runner.collect_s"):
+            for (si, ti), m in zip(lane_items, lane_ms):
+                makespans[si, ti] = m
+                cache.put(strategies[si], ti, float(m))
 
     # Scalar fallback for dynamic-period / custom-trust candidates.  The
     # process pool needs picklable strategies; ad-hoc closures (lambda
@@ -602,20 +606,21 @@ def evaluate_strategies(
             makespans[slot, ti] = m
             cache.put(strategies[slot], ti, m)
 
-    # Fill the duplicated candidates from the now-populated cache.
-    for (ckey, ti), slots in pending.items():
-        first_si, _ = seen_keys[(ckey, ti)]
-        for si in slots:
-            makespans[si, ti] = makespans[first_si, ti]
+    with reg.timer("runner.collect_s"):
+        # Fill the duplicated candidates from the now-populated cache.
+        for (ckey, ti), slots in pending.items():
+            first_si, _ = seen_keys[(ckey, ti)]
+            for si in slots:
+                makespans[si, ti] = makespans[first_si, ti]
 
-    # Average in trace order with sequential accumulation: bit-for-bit the
-    # legacy ``total += makespan; total / max(1, n)`` reduction.
-    out = []
-    for si in range(len(strategies)):
-        total = 0.0
-        for ti in range(n):
-            total += makespans[si, ti]
-        out.append(float(total / max(1, n)))
+        # Average in trace order with sequential accumulation: bit-for-bit
+        # the legacy ``total += makespan; total / max(1, n)`` reduction.
+        out = []
+        for si in range(len(strategies)):
+            total = 0.0
+            for ti in range(n):
+                total += makespans[si, ti]
+            out.append(float(total / max(1, n)))
     return out
 
 
@@ -849,8 +854,6 @@ def run_experiment(
     ``batched_traces`` select the simulation engine and the bank sampling
     path (see :func:`evaluate_strategies` / :func:`trace_bank`).
     """
-    from repro.obs.metrics import get_registry
-
     if persist is None:
         persist = _env_flag(_PERSIST_ENV)
     if batched_traces is None:
@@ -1034,8 +1037,10 @@ def _metrics_outputs(reg: Any) -> tuple[dict, dict]:
 
     Deterministic counters go into the record payload (exact-diffed);
     anything resume- or environment-dependent — the cache hit/miss split,
-    chunk counts, and all timers/gauges — rides in ``timings``, which
-    diffs exclude as provenance.
+    every ``jax.*`` counter (chunks, compile-cache outcomes and loop
+    iterations depend on the chunk size and the backend), and all
+    timers/gauges — rides in ``timings``, which diffs exclude as
+    provenance.
     """
     cnt = dict(reg.counters)
     extras = dict(reg.flat_timings())
@@ -1045,9 +1050,8 @@ def _metrics_outputs(reg: Any) -> tuple[dict, dict]:
         cnt["runner.cache_lookups"] = hits + misses
         extras["runner.cache_hits"] = hits
         extras["runner.cache_misses"] = misses
-    chunks = cnt.pop("jax.chunks", 0)    # REPRO_JAX_CHUNK-dependent
-    if chunks:
-        extras["jax.chunks"] = chunks
+    for key in [k for k in cnt if k.startswith("jax.")]:
+        extras[key] = cnt.pop(key)
     return cnt, extras
 
 

@@ -17,6 +17,9 @@ The tentpole invariants, exercised over the golden-parity cell matrix:
 
 import json
 import math
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -369,6 +372,60 @@ def test_metrics_registry_basics():
     assert reg.counters["a"] == 7 and reg.gauges["g2"] == 1.0
     reg.clear()
     assert not reg.counters and not reg.gauges and not reg.timers
+
+
+def test_timer_is_a_profiler_span(tmp_path):
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    reg = MetricsRegistry()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with reg.timer("obs.test_span_s") as lap:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert reg.timers["obs.test_span_s"] == lap.seconds > 0.0
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    names = [ev.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events]
+    assert names.count("obs.test_span_s") == 1
+
+
+def test_timer_accumulates_without_jax():
+    code = ("import sys\n"
+            "from repro.obs.metrics import MetricsRegistry\n"
+            "reg = MetricsRegistry()\n"
+            "for _ in range(2):\n"
+            "    with reg.timer('t') as lap:\n"
+            "        pass\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "assert reg.timers['t'] >= lap.seconds > 0.0\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")] + sys.path))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_jax_counters_stay_out_of_the_exact_payload():
+    from repro.experiments.runner import _metrics_outputs
+
+    reg = MetricsRegistry()
+    reg.count("runner.cells")
+    reg.count("runner.cache_hits", 3)
+    reg.count("runner.cache_misses", 5)
+    for name in ("jax.chunks", "jax.cache_hits", "jax.cache_misses",
+                 "jax.loop_iters", "jax.lane_iters", "jax.lane_slots"):
+        reg.count(name, 7)
+    reg.add_time("jax.lower_s", 0.5)
+    payload, extras = _metrics_outputs(reg)
+    assert payload == {"runner.cells": 1, "runner.cache_lookups": 8}
+    assert extras["jax.loop_iters"] == extras["jax.cache_misses"] == 7
+    assert extras["runner.cache_hits"] == 3 and extras["jax.lower_s"] == 0.5
 
 
 def test_set_registry_scoping():
