@@ -46,16 +46,25 @@ Requires ``jax_enable_x64`` so the float64 op sequence matches the
 scalar engine bit-for-bit on the CPU (float32 drifts far beyond the 1e-9
 equivalence contract).  The TPU has no native float64 and emulates it, so
 there the results agree with the numpy lanes within
-:data:`TPU_MAKESPAN_RTOL` instead of bit for bit.  Each (chunk-size, event-width, table-width)
-shape triggers one XLA compilation; reuse bank sizes across calls to
-amortize it.
+:data:`TPU_MAKESPAN_RTOL` instead of bit for bit.
+
+The process keeps the lane loops it compiled (the last
+``_PROGRAMS_MAX``), keyed by everything a program depends on: the
+event-step kernel, the platform's constants, ``cp`` and ``time_base``,
+the adaptive re-plan step, the device mesh, and the shapes and dtypes of
+the loop's arguments (chunk size, event width, table width).  So each key
+compiles once per process, and a later call with an equal key runs that
+executable with no lowering and no compile (``jax.exec_reuses``); reuse
+bank sizes across calls to keep the key.
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Any, Sequence
+import threading
+from collections import OrderedDict
+from typing import Any, NamedTuple, Sequence
 
 import numpy as np
 
@@ -172,18 +181,83 @@ def _resolve_impl() -> str:
     raise ValueError(f"unknown REPRO_JAX_PALLAS value {v!r}")
 
 
-def run_lanes_jax(bank, platform: Platform, time_base: float,
-                  lane_trace: np.ndarray, lane_period: np.ndarray,
-                  lane_kind: np.ndarray, lane_param: np.ndarray,
-                  lane_window: np.ndarray, lane_seed: np.ndarray,
-                  cp: float,
-                  lane_wmode: np.ndarray | None = None,
-                  lane_wperiod: np.ndarray | None = None,
-                  lane_adaptive: Sequence | None = None,
-                  lane_nverify: np.ndarray | None = None,
-                  lane_vcost: np.ndarray | None = None,
-                  lane_keep: np.ndarray | None = None,
-                  chunk: int | None = None) -> dict[str, Any]:
+class _Program(NamedTuple):
+    """A compiled lane loop, the holder its host callback reads, and the
+    lock a call holds from rebinding the holder to fetching the run."""
+    run: Any
+    holder: dict | None
+    lock: threading.Lock
+
+
+# The lane loops this process compiled, by everything their programs
+# depend on (`_program_key`); the least recently used leaves first.
+_PROGRAMS: OrderedDict[tuple, _Program] = OrderedDict()
+_PROGRAMS_MAX = 8
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _program_key(static: tuple, args: tuple) -> tuple:
+    """Key of the program `_build_loop` makes from ``static`` for ``args``.
+
+    A static value keys by its type too (a NumPy scalar is not weakly
+    typed, so it lowers apart from a float of the same value) and a float
+    by its bits (``-0.0`` is not ``0.0``).  Each argument keys by its tree
+    and each leaf's shape, dtype and sharding (None for a host array)."""
+    import jax
+
+    out = [(type(v), v.hex() if isinstance(v, float) else v)
+           for v in static]
+    for tree in args:
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        out.append((treedef, tuple(
+            (x.shape, x.dtype, getattr(x, "sharding", None))
+            for x in leaves)))
+    return tuple(out)
+
+
+def _lane_program(static: tuple, args: tuple, reg) -> _Program:
+    """The compiled lane loop for ``static`` (`_build_loop`'s arguments)
+    and ``args`` (its first call's): this process's, counted in
+    ``jax.exec_reuses``, or lowered and compiled now (``jax.lower_s``,
+    ``jax.xla_compile_s``, ``jax.compile_s``), where the persistent cache
+    may serve the compile."""
+    key = _program_key(static, args)
+    with _PROGRAMS_LOCK:
+        if key in _PROGRAMS:
+            _PROGRAMS.move_to_end(key)
+            reg.count("jax.exec_reuses")
+            return _PROGRAMS[key]
+    fn, holder = _build_loop(*static, args)
+    with reg.timer("jax.lower_s") as lower:
+        lowered = fn.lower(*args)
+    hits = reg.counters.get("jax.cache_hits", 0)
+    with reg.timer("jax.xla_compile_s") as comp:
+        run = lowered.compile()
+    reg.count("jax.cache_misses",
+              int(reg.counters.get("jax.cache_hits", 0) == hits))
+    reg.add_time("jax.compile_s", lower.seconds + comp.seconds)
+    prog = _Program(run, holder, threading.Lock())
+    with _PROGRAMS_LOCK:
+        _PROGRAMS[key] = prog
+        while len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
+    return prog
+
+
+def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
+                K: int, has_adaptive: bool, mesh, args: tuple):
+    """The lane loop, jitted and not yet lowered, and the holder its host
+    callback reads (None without adaptive lanes).
+
+    Everything the program bakes in is an argument: ``c``, ``cp``, ``d``,
+    ``r`` and ``time_base`` fold into it as constants, ``width``, ``TW`` and
+    ``K`` size its gathers and slots, ``has_adaptive`` adds the re-plan
+    step, and a ``mesh`` (None: one device) shards it.  ``args``, the
+    loop's (state, kc, bank) arguments, gives only its trees and ranks, for
+    the ``shard_map`` specs; no closure keeps it.  What the host callback
+    needs of a call (the lanes' configs, the platform) it reads from the
+    holder, which the caller rebinds before every run.
+    """
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -198,117 +272,6 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                                           I_NCKPT, I_NDEEP, I_NDIRTY,
                                           I_NPROC, I_NROLL, I_NVERIF,
                                           I_PHASE, I_VTC, event_step)
-
-    impl = _resolve_impl()
-    if not jax.config.jax_enable_x64:
-        raise RuntimeError(
-            "the jax backend needs float64 state for the scalar-equivalence "
-            "contract; enable it (jax.config.update('jax_enable_x64', True) "
-            "or JAX_ENABLE_X64=1) or use backend='numpy'")
-    if np.any(lane_period < platform.c):
-        raise ValueError(f"period below checkpoint {platform.c}")
-
-    L = int(lane_trace.size)
-    K = _DEF_SLOTS
-    width = bank.times.shape[1]
-    c, d, r = platform.c, platform.d, platform.r
-
-    lane_period = np.asarray(lane_period, dtype=np.float64).copy()
-    lane_kind = np.asarray(lane_kind, dtype=np.int32).copy()
-    lane_param = np.asarray(lane_param, dtype=np.float64).copy()
-    lane_window = np.asarray(lane_window, dtype=np.float64)
-    if lane_wmode is None:
-        lane_wmode = np.zeros(L, dtype=np.int8)
-    if lane_wperiod is None:
-        lane_wperiod = np.zeros(L, dtype=np.float64)
-    if lane_adaptive is None:
-        lane_adaptive = [None] * L
-    if lane_nverify is None:
-        lane_nverify = np.zeros(L, dtype=np.int32)
-    if lane_vcost is None:
-        lane_vcost = np.zeros(L, dtype=np.float64)
-    if lane_keep is None:
-        lane_keep = np.ones(L, dtype=np.int32)
-    lane_nverify = np.asarray(lane_nverify).astype(np.int32)
-    lane_vcost = np.asarray(lane_vcost, dtype=np.float64)
-    lane_keep = np.asarray(lane_keep).astype(np.int32)
-    if np.any(lane_nverify < 0):
-        raise ValueError("n_verify must be >= 0")
-    if np.any(~np.isfinite(lane_vcost)) or np.any(lane_vcost < 0.0):
-        raise ValueError("verify_cost must be finite and >= 0")
-    if np.any(lane_keep < 1):
-        raise ValueError("keep_ckpts must be >= 1")
-
-    within = np.asarray(lane_wmode) == _WMODE_WITHIN
-    if np.any(within & (lane_wperiod <= cp)):
-        bad = float(np.asarray(lane_wperiod)[within & (lane_wperiod <= cp)][0])
-        raise ValueError(f"window_period {bad} <= C_p {cp}: no work fits "
-                         f"between in-window checkpoints")
-    lane_wwp = np.where(within, lane_wperiod - cp, np.inf)
-
-    # Adaptive lanes (mirrors the NumPy engine's setup: plan state is
-    # per-lane, Never-trust adaptive lanes become Threshold(+inf)).
-    ad_act = np.array([a is not None for a in lane_adaptive], dtype=bool)
-    has_adaptive = bool(ad_act.any())
-    if has_adaptive:
-        bad_trust = ad_act & ~np.isin(lane_kind,
-                                      (_TRUST_NEVER, _TRUST_THRESHOLD))
-        if bad_trust.any():
-            raise ValueError(
-                "adaptive re-planning requires a Threshold or Never trust "
-                "policy (the plan sets the threshold)")
-        never = ad_act & (lane_kind == _TRUST_NEVER)
-        lane_kind[never] = _TRUST_THRESHOLD
-        lane_param[never] = np.inf
-        ad_minp = np.array([(a.min_preds if a else np.inf)
-                            for a in lane_adaptive], dtype=np.float64)
-        ad_minf = np.array([(a.min_faults if a else np.inf)
-                            for a in lane_adaptive], dtype=np.float64)
-        ad_tol = np.array([(a.tol if a else 0.0)
-                           for a in lane_adaptive], dtype=np.float64)
-        ad_dec = np.array([(a.decay if a else 1.0)
-                           for a in lane_adaptive], dtype=np.float64)
-        ad_estmu = np.array(
-            [bool(a is not None and getattr(a, "estimate_mu", False))
-             for a in lane_adaptive], dtype=bool)
-        ad_pr0 = np.array([(a.prior_recall if a else 0.0)
-                           for a in lane_adaptive], dtype=np.float64)
-        ad_pp0 = np.array([(a.prior_precision if a else 0.0)
-                           for a in lane_adaptive], dtype=np.float64)
-        from repro.predictors.estimator import P_HAT_MIN, maybe_replan
-    else:
-        ad_estmu = np.zeros(L, dtype=bool)
-
-    from repro.obs.metrics import get_registry
-    reg = get_registry()
-    _listen_for_cache_hits()
-    with reg.timer("jax.draw_tables_s"):
-        tab = _draw_tables(bank, lane_trace, lane_kind, lane_window,
-                           lane_seed)
-    TW = tab.shape[1]
-
-    # The trace bank enters the loop as an argument (not a closed-over
-    # constant): one copy per device, replicated across a sharded mesh.
-    bank_arrs = {"times": np.asarray(bank.times, dtype=np.float64),
-                 "kinds": bank.kinds.astype(np.int32),
-                 "wins": (bank.windows if bank.windows is not None
-                          else np.full_like(bank.times, -1.0))}
-    n_ev = bank.n_events[lane_trace].astype(np.int32)
-
-    # -- chunking / sharding layout -----------------------------------------
-    env_chunk = os.environ.get("REPRO_JAX_CHUNK", "").strip()
-    if chunk is None and env_chunk:
-        chunk = int(env_chunk)
-    CL = L if (chunk is None or chunk <= 0) else min(int(chunk), L)
-    CL = max(CL, 1)
-
-    shard_env = os.environ.get("REPRO_JAX_SHARD", "auto").strip().lower()
-    devices = jax.devices()
-    use_shard = (not has_adaptive and shard_env != "0"
-                 and (len(devices) > 1 or shard_env in ("1", "force")))
-    n_shards = len(devices) if use_shard else 1
-    if use_shard and CL % n_shards:
-        CL += n_shards - CL % n_shards
 
     # -- per-lane step: event pop -------------------------------------------
     def _push_one(def_time, def_seq, next_seq, overflow, push, date):
@@ -433,8 +396,11 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         return dict(s, **out), tmp
 
     # -- adaptive replan fixup (between pop and arrival) --------------------
+    holder = None
     if has_adaptive:
-        holder: dict[str, Any] = {"cfgs": list(lane_adaptive)}
+        from repro.predictors.estimator import P_HAT_MIN, maybe_replan
+
+        holder = {}
 
         def _host_replan(fire, ntp, nfp, nuf, gs, gn, prw, ppw, pmuw, period,
                          tparam, n_replans):
@@ -443,8 +409,9 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             pr, pp, pmu = _f64(prw), _f64(ppw), _f64(pmuw)
             period, tparam = np.array(period), np.array(tparam)
             n_replans = np.array(n_replans)
+            cfgs, platform = holder["cfgs"], holder["platform"]
             for lane in np.nonzero(fire)[0]:
-                cfg = holder["cfgs"][lane]
+                cfg = cfgs[lane]
                 if cfg is None:      # padding lane: never evaluated
                     continue
                 mu_hat = None
@@ -686,21 +653,157 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             lambda s: ~(jnp.all(s["finished"]) | jnp.any(s["overflow"])),
             lambda s: _body(s, kc, bk), state)
 
+    if mesh is None:
+        return jax.jit(_loop, donate_argnums=0), holder
+    from jax.sharding import PartitionSpec as P
+
+    def _specs(tree):
+        return jax.tree_util.tree_map(
+            lambda v: P("i") if np.ndim(v) == 1 else P("i", None), tree)
+
+    state, kc, bank = args
+    bank_specs = jax.tree_util.tree_map(lambda _: P(), bank)
+    return jax.jit(jax.shard_map(
+        _loop, mesh=mesh,
+        in_specs=(_specs(state), _specs(kc), bank_specs),
+        out_specs=_specs(state), check_vma=False),
+        donate_argnums=0), holder
+
+
+def run_lanes_jax(bank, platform: Platform, time_base: float,
+                  lane_trace: np.ndarray, lane_period: np.ndarray,
+                  lane_kind: np.ndarray, lane_param: np.ndarray,
+                  lane_window: np.ndarray, lane_seed: np.ndarray,
+                  cp: float,
+                  lane_wmode: np.ndarray | None = None,
+                  lane_wperiod: np.ndarray | None = None,
+                  lane_adaptive: Sequence | None = None,
+                  lane_nverify: np.ndarray | None = None,
+                  lane_vcost: np.ndarray | None = None,
+                  lane_keep: np.ndarray | None = None,
+                  chunk: int | None = None) -> dict[str, Any]:
+    import jax
+
+    impl = _resolve_impl()
+    if not jax.config.jax_enable_x64:
+        raise RuntimeError(
+            "the jax backend needs float64 state for the scalar-equivalence "
+            "contract; enable it (jax.config.update('jax_enable_x64', True) "
+            "or JAX_ENABLE_X64=1) or use backend='numpy'")
+    if np.any(lane_period < platform.c):
+        raise ValueError(f"period below checkpoint {platform.c}")
+
+    L = int(lane_trace.size)
+    K = _DEF_SLOTS
+    width = bank.times.shape[1]
+    c, d, r = platform.c, platform.d, platform.r
+
+    lane_period = np.asarray(lane_period, dtype=np.float64).copy()
+    lane_kind = np.asarray(lane_kind, dtype=np.int32).copy()
+    lane_param = np.asarray(lane_param, dtype=np.float64).copy()
+    lane_window = np.asarray(lane_window, dtype=np.float64)
+    if lane_wmode is None:
+        lane_wmode = np.zeros(L, dtype=np.int8)
+    if lane_wperiod is None:
+        lane_wperiod = np.zeros(L, dtype=np.float64)
+    if lane_adaptive is None:
+        lane_adaptive = [None] * L
+    if lane_nverify is None:
+        lane_nverify = np.zeros(L, dtype=np.int32)
+    if lane_vcost is None:
+        lane_vcost = np.zeros(L, dtype=np.float64)
+    if lane_keep is None:
+        lane_keep = np.ones(L, dtype=np.int32)
+    lane_nverify = np.asarray(lane_nverify).astype(np.int32)
+    lane_vcost = np.asarray(lane_vcost, dtype=np.float64)
+    lane_keep = np.asarray(lane_keep).astype(np.int32)
+    if np.any(lane_nverify < 0):
+        raise ValueError("n_verify must be >= 0")
+    if np.any(~np.isfinite(lane_vcost)) or np.any(lane_vcost < 0.0):
+        raise ValueError("verify_cost must be finite and >= 0")
+    if np.any(lane_keep < 1):
+        raise ValueError("keep_ckpts must be >= 1")
+
+    within = np.asarray(lane_wmode) == _WMODE_WITHIN
+    if np.any(within & (lane_wperiod <= cp)):
+        bad = float(np.asarray(lane_wperiod)[within & (lane_wperiod <= cp)][0])
+        raise ValueError(f"window_period {bad} <= C_p {cp}: no work fits "
+                         f"between in-window checkpoints")
+    lane_wwp = np.where(within, lane_wperiod - cp, np.inf)
+
+    # Adaptive lanes (mirrors the NumPy engine's setup: plan state is
+    # per-lane, Never-trust adaptive lanes become Threshold(+inf)).
+    ad_act = np.array([a is not None for a in lane_adaptive], dtype=bool)
+    has_adaptive = bool(ad_act.any())
+    if has_adaptive:
+        bad_trust = ad_act & ~np.isin(lane_kind,
+                                      (_TRUST_NEVER, _TRUST_THRESHOLD))
+        if bad_trust.any():
+            raise ValueError(
+                "adaptive re-planning requires a Threshold or Never trust "
+                "policy (the plan sets the threshold)")
+        never = ad_act & (lane_kind == _TRUST_NEVER)
+        lane_kind[never] = _TRUST_THRESHOLD
+        lane_param[never] = np.inf
+        ad_minp = np.array([(a.min_preds if a else np.inf)
+                            for a in lane_adaptive], dtype=np.float64)
+        ad_minf = np.array([(a.min_faults if a else np.inf)
+                            for a in lane_adaptive], dtype=np.float64)
+        ad_tol = np.array([(a.tol if a else 0.0)
+                           for a in lane_adaptive], dtype=np.float64)
+        ad_dec = np.array([(a.decay if a else 1.0)
+                           for a in lane_adaptive], dtype=np.float64)
+        ad_estmu = np.array(
+            [bool(a is not None and getattr(a, "estimate_mu", False))
+             for a in lane_adaptive], dtype=bool)
+        ad_pr0 = np.array([(a.prior_recall if a else 0.0)
+                           for a in lane_adaptive], dtype=np.float64)
+        ad_pp0 = np.array([(a.prior_precision if a else 0.0)
+                           for a in lane_adaptive], dtype=np.float64)
+    else:
+        ad_estmu = np.zeros(L, dtype=bool)
+
+    from repro.obs.metrics import get_registry
+    reg = get_registry()
+    _listen_for_cache_hits()
+    with reg.timer("jax.draw_tables_s"):
+        tab = _draw_tables(bank, lane_trace, lane_kind, lane_window,
+                           lane_seed)
+    TW = tab.shape[1]
+
+    # The trace bank enters the loop as an argument (not a closed-over
+    # constant): one copy per device, replicated across a sharded mesh.
+    bank_arrs = {"times": np.asarray(bank.times, dtype=np.float64),
+                 "kinds": bank.kinds.astype(np.int32),
+                 "wins": (bank.windows if bank.windows is not None
+                          else np.full_like(bank.times, -1.0))}
+    n_ev = bank.n_events[lane_trace].astype(np.int32)
+
+    # -- chunking / sharding layout -----------------------------------------
+    env_chunk = os.environ.get("REPRO_JAX_CHUNK", "").strip()
+    if chunk is None and env_chunk:
+        chunk = int(env_chunk)
+    CL = L if (chunk is None or chunk <= 0) else min(int(chunk), L)
+    CL = max(CL, 1)
+
+    shard_env = os.environ.get("REPRO_JAX_SHARD", "auto").strip().lower()
+    devices = jax.devices()
+    use_shard = (not has_adaptive and shard_env != "0"
+                 and (len(devices) > 1 or shard_env in ("1", "force")))
+    n_shards = len(devices) if use_shard else 1
+    if use_shard and CL % n_shards:
+        CL += n_shards - CL % n_shards
+
     if use_shard:
         from jax.sharding import Mesh, NamedSharding
         from jax.sharding import PartitionSpec as P
         mesh = Mesh(np.asarray(devices), ("i",))
-
-        def _specs(tree):
-            return jax.tree_util.tree_map(
-                lambda v: P("i") if np.ndim(v) == 1 else P("i", None), tree)
-
-        bank_specs = jax.tree_util.tree_map(lambda _: P(), bank_arrs)
         bank_to = NamedSharding(mesh, P())
     else:
-        bank_to = None
+        mesh = bank_to = None
     with reg.timer("jax.bank_put_s"):
         bank_dev = jax.device_put(bank_arrs, bank_to)
+    static = (impl, c, cp, d, r, time_base, width, TW, K, has_adaptive, mesh)
 
     # -- chunk driver --------------------------------------------------------
     def _init_chunk(sl: slice, n_real: int):
@@ -791,7 +894,7 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             )
         return state, kc
 
-    run_jit = None
+    prog = None
     out_keys = ("now", "n_faults", "n_faults_hit", "n_predictions",
                 "n_trusted", "n_trusted_true", "n_ignored",
                 "n_periodic_ckpts", "n_prockpts", "n_rollbacks",
@@ -809,31 +912,19 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         sl = slice(lo, lo + n_real)
         with reg.timer("jax.init_chunk_s"):
             state, kc = _init_chunk(sl, n_real)
-        if has_adaptive:
-            cfgs = list(lane_adaptive[lo:lo + n_real])
-            holder["cfgs"] = cfgs + [None] * (CL - n_real)
-        if run_jit is None:
-            # One compilation serves every chunk; timed apart from the runs.
-            if use_shard:
-                fn = jax.jit(jax.shard_map(
-                    _loop, mesh=mesh,
-                    in_specs=(_specs(state), _specs(kc), bank_specs),
-                    out_specs=_specs(state), check_vma=False),
-                    donate_argnums=0)
-            else:
-                fn = jax.jit(_loop, donate_argnums=0)
-            with reg.timer("jax.lower_s") as lower:
-                lowered = fn.lower(state, kc, bank_dev)
-            hits = reg.counters.get("jax.cache_hits", 0)
-            with reg.timer("jax.xla_compile_s") as comp:
-                run_jit = lowered.compile()
-            reg.count("jax.cache_misses",
-                      int(reg.counters.get("jax.cache_hits", 0) == hits))
-            reg.add_time("jax.compile_s", lower.seconds + comp.seconds)
-        with reg.timer("jax.dispatch_s") as dispatch:
-            out = run_jit(state, kc, bank_dev)
-        with reg.timer("jax.fetch_s") as fetch:
-            final = jax.device_get(out)
+        if prog is None:
+            # One compilation serves every chunk, and every later call
+            # whose program is the same; timed apart from the runs.
+            prog = _lane_program(static, (state, kc, bank_dev), reg)
+        with prog.lock:
+            if has_adaptive:
+                cfgs = list(lane_adaptive[lo:lo + n_real])
+                prog.holder.update(cfgs=cfgs + [None] * (CL - n_real),
+                                   platform=platform)
+            with reg.timer("jax.dispatch_s") as dispatch:
+                out = prog.run(state, kc, bank_dev)
+            with reg.timer("jax.fetch_s") as fetch:
+                final = jax.device_get(out)
         reg.add_time("jax.run_s", dispatch.seconds + fetch.seconds)
         reg.count("jax.chunks")
         # Each shard's while loop runs a contiguous block of lanes until

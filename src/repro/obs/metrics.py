@@ -35,6 +35,7 @@ Metric names used by the instrumented call sites:
 ``jax.chunks``                          lane chunks driven (counter)
 ``jax.cache_hits``                      persistent-cache hits (counter)
 ``jax.cache_misses``                    loop compiles the cache missed
+``jax.exec_reuses``                     calls run on a loop compiled earlier
 ``jax.loop_iters``                      while-loop iterations, per shard
 ``jax.lane_iters``                      iterations real lanes worked
 ``jax.lane_slots``                      iterations x lanes, padding too

@@ -35,6 +35,9 @@ def x64():
 
 
 def test_simulation_phase_tiny(x64):
+    from repro.core import batch_jax
+
+    batch_jax._PROGRAMS.clear()     # the grid's first call compiles
     out = cs.simulation_phase(n_traces=4, n_periods=3, adaptive_traces=4,
                               adaptive_periods=2)
     grid, ad = out["paper_grid"], out["adaptive"]

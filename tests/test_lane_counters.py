@@ -7,8 +7,9 @@ it saw as JSON; the tests read that.  Cases: lanes that all share one
 trace and period (in lockstep), the same lanes mixed with others, one
 at a time, in chunks smaller than the lane count, and sharded over four
 virtual devices; an identical call
-twice (the second served by the cache); one ``evaluate_strategies`` call
-under the profiler.
+twice, the process's compiled programs cleared between (so the persistent
+cache serves the second compile); one ``evaluate_strategies`` call under
+the profiler, compiling cold.
 """
 
 import json
@@ -31,6 +32,7 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 from jax.profiler import ProfileData
 
+from repro.core import batch_jax
 from repro.core.batch import _pack_bank, simulate_lanes, trust_code
 from repro.core.batch_jax import run_lanes_jax
 from repro.core.policies import Strategy
@@ -52,7 +54,9 @@ def seeds(tr):
     return 5 + 7919 * np.asarray(tr)
 
 
-def lanes(tr, periods, chunk=None):
+def lanes(tr, periods, chunk=None, cold=False):
+    if cold:        # compile again: no program of this process serves it
+        batch_jax._PROGRAMS.clear()
     tr = np.asarray(tr)
     n = tr.size
     kind, param = trust_code(TRUST)
@@ -72,7 +76,7 @@ def lanes(tr, periods, chunk=None):
 
 os.environ["REPRO_JAX_SHARD"] = "0"
 res = {"same": lanes([0] * 4, [1200.0] * 4),
-       "same_again": lanes([0] * 4, [1200.0] * 4),
+       "same_again": lanes([0] * 4, [1200.0] * 4, cold=True),
        "mixed": lanes(*MIXED),
        "chunked": lanes(*MIXED, chunk=4),
        "alone": [lanes([t], [p])["n_iters"][0] for t, p in zip(*MIXED)]}
@@ -84,6 +88,7 @@ res["numpy"] = simulate_lanes(
     periods=MIXED[1], trusts=[TRUST] * 6, windows=[0.0] * 6,
     seeds=seeds(MIXED[0]), backend="numpy").tolist()
 
+batch_jax._PROGRAMS.clear()    # the compile's spans are among those seen
 reg = MetricsRegistry()
 prev = set_registry(reg)
 opts = jax.profiler.ProfileOptions()
@@ -196,7 +201,13 @@ def test_cache_misses_count_compiles_the_cache_did_not_serve(seen):
 @pytest.mark.parametrize("case", ["same", "mixed", "chunked"])
 def test_totals_are_the_sums_of_their_spans(seen, case):
     t = seen[case]["timers"]
-    assert t["jax.compile_s"] == t["jax.lower_s"] + t["jax.xla_compile_s"]
+    if case == "chunked":       # four lanes a chunk: "same_again"'s program
+        assert seen[case]["counters"]["jax.exec_reuses"] == 1
+        assert not {"jax.compile_s", "jax.lower_s",
+                    "jax.xla_compile_s"} & set(t)
+    else:
+        assert t["jax.compile_s"] == (t["jax.lower_s"]
+                                      + t["jax.xla_compile_s"])
     if seen[case]["counters"]["jax.chunks"] == 1:
         assert t["jax.run_s"] == t["jax.dispatch_s"] + t["jax.fetch_s"]
     else:           # a sum per chunk, added in another order
