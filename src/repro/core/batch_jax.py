@@ -269,9 +269,10 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                                           F_TRECOV, F_TVERIFY, F_VREM,
                                           F_VWP, F_WINEND, F_WINREM, F_WPP,
                                           F_WREM, F_WWP, I_CORR, I_FIN,
-                                          I_NCKPT, I_NDEEP, I_NDIRTY,
-                                          I_NPROC, I_NROLL, I_NVERIF,
-                                          I_PHASE, I_VTC, event_step)
+                                          I_LAST, I_NCKPT, I_NDEEP,
+                                          I_NDIRTY, I_NPROC, I_NROLL,
+                                          I_NVERIF, I_PHASE, I_VTC,
+                                          event_step)
 
     # -- per-lane step: event pop -------------------------------------------
     def _push_one(def_time, def_seq, next_seq, overflow, push, date):
@@ -595,7 +596,8 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                          s["n_deep_rollbacks"], s["n_dirty"],
                          s["corrupted"].astype(jnp.int32),
                          s["verify_then_ckpt"].astype(jnp.int32),
-                         kc["nv"], kc["keep"]])
+                         kc["nv"], kc["keep"],
+                         s["last_period"].astype(jnp.int32)])
         for _ in range(_ADV_PASSES):
             fs, is_ = event_step(fs, is_, c=c, cp=cp, d=d, r=r,
                                  time_base=time_base, impl=impl)
@@ -613,7 +615,8 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                     n_rollbacks=is_[I_NROLL], n_verifications=is_[I_NVERIF],
                     n_deep_rollbacks=is_[I_NDEEP], n_dirty=is_[I_NDIRTY],
                     corrupted=is_[I_CORR] != 0,
-                    verify_then_ckpt=is_[I_VTC] != 0)
+                    verify_then_ckpt=is_[I_VTC] != 0,
+                    last_period=is_[I_LAST] != 0)
 
     def _push_all(s, push, date):
         """Full-array deferred-fault insert (the pop-site pushes)."""
@@ -825,6 +828,7 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             "phase": np.full(n, _WORK, i4),
             "phase_end": np.full(n, np.inf, f8),
             "wpp": wpp0, "w_rem": np.minimum(wpp0, time_base),
+            "last_period": time_base <= wpp0,
             "win_end": np.full(n, -np.inf, f8),
             "win_rem": np.full(n, np.inf, f8),
             "finished": np.zeros(n, bool),
@@ -935,6 +939,15 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         reg.count("jax.loop_iters", int(loops.sum()))
         reg.count("jax.lane_iters", int(iters[:n_real].sum()))
         reg.count("jax.lane_slots", int(loops.sum()) * (CL // n_shards))
+        # Periodic checkpoints of real lanes, and the lanes whose job end
+        # the last-period flag decided below the scalar test's threshold
+        # (0 in float64; on emulated float64, the lanes that test would
+        # have given one more period).
+        reg.count("jax.lane_ckpts",
+                  int(final["n_periodic_ckpts"][:n_real].sum()))
+        reg.count("jax.job_end_slack_lanes", int(np.sum(
+            final["finished"][:n_real]
+            & (final["saved"][:n_real] < time_base - 1e-9))))
         if final["overflow"].any():
             reg.count("engine.deferred_overflows")
             raise RuntimeError(

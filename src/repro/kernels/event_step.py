@@ -62,13 +62,15 @@ F_FIELDS = ("now", "done", "saved", "period_start", "phase_end", "wpp",
 N_F = len(F_FIELDS)
 
 # Int32 state rows (n_verify/keep_ckpts are static per-lane knobs;
-# corrupted/verify_then_ckpt are 0/1 flags).
+# corrupted/verify_then_ckpt/last_period are 0/1 flags).  last_period is
+# set at each renewal whose work is the job's remainder
+# (``time_base - saved <= wpp``): that period's checkpoint ends the job.
 I_FIELDS = ("phase", "finished", "n_periodic_ckpts", "n_proactive_ckpts",
             "n_rollbacks", "n_verifications", "n_deep_rollbacks",
             "n_dirty", "corrupted", "verify_then_ckpt", "n_verify",
-            "keep_ckpts")
+            "keep_ckpts", "last_period")
 (I_PHASE, I_FIN, I_NCKPT, I_NPROC, I_NROLL, I_NVERIF, I_NDEEP, I_NDIRTY,
- I_CORR, I_VTC, I_NV, I_KEEP) = range(12)
+ I_CORR, I_VTC, I_NV, I_KEEP, I_LAST) = range(13)
 N_I = len(I_FIELDS)
 
 LANE_BLOCK = 1024
@@ -83,6 +85,14 @@ def _advance_math(fs, is_, *, c: float, cp: float, d: float, r: float,
     proactive cadence and the window end; completed phases run their
     ``_complete_phase`` transitions.  Lanes with ``now >= target`` (or
     finished) are untouched, so padding columns are inert.
+
+    The job ends at the checkpoint of a period flagged ``last_period``, or
+    of any period whose save reaches ``time_base - 1e-9`` (the scalar
+    test).  The flag carries the float64 decision onto emulated float64,
+    whose rounding (about 1e-13 relative) can leave ``saved`` a few 1e-7 s
+    short of ``time_base`` after the last period.  The flagged period still
+    ends exactly: its last work chunk takes ``w_rem`` from itself, which is
+    0 in any arithmetic, and its checkpoint follows.
     """
     fin_thresh = time_base - 1e-9
     now = fs[F_NOW]
@@ -102,6 +112,7 @@ def _advance_math(fs, is_, *, c: float, cp: float, d: float, r: float,
     corrupted = is_[I_CORR] != 0
     vtc = is_[I_VTC] != 0
     n_dirty = is_[I_NDIRTY]
+    last = is_[I_LAST] != 0
 
     adv = ~finished & (now < target)
     in_work = adv & (phase == _WORK)
@@ -172,7 +183,7 @@ def _advance_math(fs, is_, *, c: float, cp: float, d: float, r: float,
 
     # Final-checkpoint acceptance check: a corrupted lane at the end of
     # the job detects instead of finishing.
-    at_end = ck & (saved >= fin_thresh)
+    at_end = ck & (last | (saved >= fin_thresh))
     det_ck = at_end & corrupted
     fin = at_end & ~corrupted
     finished = finished | fin
@@ -213,7 +224,9 @@ def _advance_math(fs, is_, *, c: float, cp: float, d: float, r: float,
     phase_end = jnp.where(renew, jnp.inf, phase_end)
     period_start = jnp.where(renew, now, period_start)
     wpp = jnp.where(renew, jnp.maximum(1e-9, fs[F_PERIOD] - c), fs[F_WPP])
-    w_rem = jnp.where(renew, jnp.minimum(wpp, time_base - saved), w_rem)
+    rest = time_base - saved
+    w_rem = jnp.where(renew, jnp.minimum(wpp, rest), w_rem)
+    last = jnp.where(renew, rest <= wpp, last)
     v_wp = jnp.where(renew & verify_on,
                      wpp / jnp.maximum(nv, 1).astype(wpp.dtype), v_wp)
     v_rem = jnp.where(renew, v_wp, v_rem)
@@ -253,7 +266,7 @@ def _advance_math(fs, is_, *, c: float, cp: float, d: float, r: float,
                         n_dirty.astype(jnp.int32),
                         corrupted.astype(jnp.int32),
                         vtc.astype(jnp.int32),
-                        nv, keep])
+                        nv, keep, last.astype(jnp.int32)])
     return fs_out, is_out
 
 

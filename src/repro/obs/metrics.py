@@ -39,6 +39,9 @@ Metric names used by the instrumented call sites:
 ``jax.loop_iters``                      while-loop iterations, per shard
 ``jax.lane_iters``                      iterations real lanes worked
 ``jax.lane_slots``                      iterations x lanes, padding too
+``jax.lane_ckpts``                      periodic checkpoints of real lanes
+``jax.job_end_slack_lanes``             job ends the last-period flag
+                                        decided below ``time_base - 1e-9``
 ``jax.shards``                          devices the last call sharded over
 ``engine.deferred_overflows``           deferred-fault capacity trips
 ``fleet.faults`` / ``fleet.repair_waits``  fleet coupling events

@@ -33,6 +33,7 @@ banks remain reproducible per (seed, scenario).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Mapping
 
 import numpy as np
@@ -52,8 +53,15 @@ __all__ = [
 
 
 def _false_mean(recall: float, precision: float, mu: float) -> float:
-    """Mean time between false predictions: p·mu / (r·(1-p)) (paper §2.3)."""
-    return precision * mu / (recall * (1.0 - precision))
+    """Mean time between false predictions: p·mu / (r·(1-p)) (paper §2.3).
+
+    Infinite (no false prediction) where r·(1-p) underflows to 0, as for a
+    subnormal recall.
+    """
+    den = recall * (1.0 - precision)
+    if den == 0.0:
+        return math.inf
+    return precision * mu / den
 
 
 @dataclasses.dataclass(frozen=True)

@@ -419,7 +419,8 @@ def test_jax_counters_stay_out_of_the_exact_payload():
     reg.count("runner.cache_hits", 3)
     reg.count("runner.cache_misses", 5)
     for name in ("jax.chunks", "jax.cache_hits", "jax.cache_misses",
-                 "jax.loop_iters", "jax.lane_iters", "jax.lane_slots"):
+                 "jax.loop_iters", "jax.lane_iters", "jax.lane_slots",
+                 "jax.lane_ckpts", "jax.job_end_slack_lanes"):
         reg.count(name, 7)
     reg.add_time("jax.lower_s", 0.5)
     payload, extras = _metrics_outputs(reg)

@@ -179,6 +179,16 @@ def test_bursty_preserves_rate_but_clusters():
     assert gaps.std() / gaps.mean() > 1.3            # clustered (CV >> 1)
 
 
+@pytest.mark.parametrize("name", ["oracle", "bursty"])
+def test_subnormal_recall_gives_no_false_predictions(name):
+    """r·(1-p) underflows to 0: the false-alarm mean is infinite, not 0/0."""
+    model = build_predictor(name, 5e-324, 0.5)
+    tr = make_event_trace(Exponential(1.0), 100.0, 5e-324, 0.5, 100_000.0,
+                          np.random.default_rng(4), predictor_model=model)
+    assert tr.times.size > 0
+    assert not (tr.kinds == FALSE_PRED).any()
+
+
 def test_predictor_models_only_draw_from_their_rng():
     """Two generations from equal seeds are identical (reproducibility)."""
     for name in list_predictors():
