@@ -22,7 +22,18 @@ in-window fault offsets) is **pre-drawn** per lane into stream-prefix
 tables: every scalar-engine draw consumes exactly one float64 from the
 lane's ``default_rng(seed)`` stream (``uniform(0, w)`` is bit-for-bit
 ``w * random()``), so the loop carries one cursor per lane and consumes
-``table[lane, cursor]`` at exactly the scalar engine's draw sites.
+the table's value at that cursor at exactly the scalar engine's draw
+sites.
+
+Events and draws are read from a **staged window** in the loop's state,
+not from the bank and the table: once every :data:`_EV_BLOCK` (B)
+iterations a refill loads each lane's next events (two aligned B-blocks
+of its bank row) and next draws (two aligned 2B-blocks of its table row,
+or the whole row where it holds at most 4B draws), and the iterations in
+between read them with a one-hot select.  A lane pops at most one event
+and draws at most twice an iteration, so the window holds every value
+the block reads: the same elements as a direct read, with no arithmetic
+changed.
 
 Adaptive re-planning runs the estimator counters (and the online-MTBF
 gap statistics of ``AdaptiveConfig(estimate_mu=True)``) on-device at the
@@ -95,7 +106,13 @@ _WMODE_INSTANT, _WMODE_WITHIN = range(2)
 _PC_POP, _PC_FAULT, _PC_PRED, _PC_FINAL, _PC_SILENT = range(5)
 _DEF_SLOTS = 8          # deferred-fault capacity; overflow is detected
 _BIG_SEQ = np.iinfo(np.int32).max
+_I32_MIN = np.iinfo(np.int32).min
 _ADV_PASSES = 4         # schedule steps per loop iteration (cf. numpy's 6)
+# Loop iterations between refills of the staged event and draw windows.
+# A refill gathers whole aligned blocks (a row gather, which the TPU runs
+# as fast as one gather of a value a lane); a per-lane read gathers every
+# iteration, and an unaligned slice a lane lowers to a serial copy a lane.
+_EV_BLOCK = 64
 
 
 def _draw_tables(bank, lane_trace: np.ndarray, lane_kind: np.ndarray,
@@ -245,13 +262,14 @@ def _lane_program(static: tuple, args: tuple, reg) -> _Program:
 
 
 def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
-                K: int, has_adaptive: bool, mesh, args: tuple):
+                K: int, B: int, has_adaptive: bool, mesh, args: tuple):
     """The lane loop, jitted and not yet lowered, and the holder its host
     callback reads (None without adaptive lanes).
 
     Everything the program bakes in is an argument: ``c``, ``cp``, ``d``,
     ``r`` and ``time_base`` fold into it as constants, ``width``, ``TW`` and
-    ``K`` size its gathers and slots, ``has_adaptive`` adds the re-plan
+    ``K`` size its windows and slots, ``B`` is the iterations between
+    refills of the staged windows, ``has_adaptive`` adds the re-plan
     step, and a ``mesh`` (None: one device) shards it.  ``args``, the
     loop's (state, kc, bank) arguments, gives only its trees and ranks, for
     the ``shard_map`` specs; no closure keeps it.  What the host callback
@@ -285,13 +303,9 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
         next_seq = jnp.where(push, next_seq + 1, next_seq)
         return def_time, def_seq, next_seq, overflow
 
-    def _pop_one(s, k, bk):
+    def _pop_one(s, k, rd):
         pop = ~s["finished"] & (s["pc"] == _PC_POP)
-        col = jnp.minimum(s["cursor"], width - 1)
-        have = s["cursor"] < k["n_ev"]
-        t_tr = jnp.where(have, bk["times"][k["tr"], col], jnp.inf)
-        k_tr = jnp.where(have, bk["kinds"][k["tr"], col], -1)
-        w_ev = jnp.where(have, bk["wins"][k["tr"], col], -1.0)
+        t_tr, k_tr, w_ev = rd["t_tr"], rd["k_tr"], rd["w_ev"]
         min_t = s["def_time"].min()
         tie = s["def_time"] == min_t
         seqm = jnp.where(tie, s["def_seq"], _BIG_SEQ)
@@ -380,7 +394,7 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
         # rounding breaks bitwise parity with numpy's `t + uniform(0, w)`.
         w_eff = jnp.where(w_ev < 0.0, k["window"], w_ev)
         draw_win = is_true & (w_eff > 0.0)
-        u = k["tab"][jnp.minimum(s["cur"], TW - 1)]
+        u = rd["u"]
         cur = s["cur"] + draw_win
         ckpt_start = t_tr - cp
         honour = is_pred & (ckpt_start >= s["now"])
@@ -483,7 +497,7 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                         replan_eval=jnp.zeros_like(fire))
 
     # -- per-lane step: event arrivals --------------------------------------
-    def _arrive_one(s, k):
+    def _arrive_one(s, k, u2):
         active = ~s["finished"]
         now, phase, phase_end = s["now"], s["phase"], s["phase_end"]
         target = s["target"]
@@ -546,7 +560,6 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
         working = arr_p & (phase == _WORK)
         offset = s["pred_t"] - s["period_start"]
         draw_q = working & (k["kind"] == _TRUST_FIXED_Q)
-        u2 = k["tab"][jnp.minimum(s["cur"], TW - 1)]
         cur = s["cur"] + draw_q
         trusted = working & ((k["kind"] == _TRUST_ALWAYS)
                              | ((k["kind"] == _TRUST_THRESHOLD)
@@ -632,9 +645,64 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                                        s["next_seq"]),
                     overflow=overflow)
 
-    def _body(s, kc, bk):
+    # -- staged read windows ------------------------------------------------
+    # A lane pops at most one event and draws at most twice an iteration
+    # (once in the pop, once in the arrival; the re-plan step moves neither
+    # cursor), so the B iterations of a block read at most the B events and
+    # the 2B draws from the cursors at its start.  A window is the two
+    # aligned blocks of the lane's row that begin at the block holding the
+    # cursor (events in B-blocks, draws in 2B-blocks), clamped to the row's
+    # last two, so it holds them all, reads clamped to a row's last column
+    # included.  A table of at most two draw blocks is its own window.
+    TB = 2 * B
+    n_eb = max(2, -(-width // B))
+    n_tb = max(2, -(-TW // TB))
+    tab_whole = TW <= 2 * TB
+
+    def _blocked(a, n_blk, size):
+        """``a``'s rows, padded to ``n_blk`` blocks, one block a row."""
+        a = jnp.pad(a, ((0, 0), (0, n_blk * size - a.shape[1])))
+        return a.reshape(-1, size)
+
+    def _window(blocks, row0, blk):
+        """Blocks ``blk`` and ``blk + 1`` of each lane's row (its blocks
+        start at ``row0``), lane axis last: one gather of whole rows."""
+        idx = row0 + blk
+        two = blocks[jnp.stack([idx, idx + 1], axis=1)]
+        return two.reshape(idx.shape[0], -1).T
+
+    def _refill(s, kc, bk_blocks, tab_blocks):
+        blk = jnp.minimum(s["cursor"] // B, n_eb - 2)
+        w = {name: _window(a, kc["tr"] * n_eb, blk)
+             for name, a in bk_blocks.items()}
+        w["ev_base"] = blk * B
+        if not tab_whole:
+            tblk = jnp.minimum(s["cur"] // TB, n_tb - 2)
+            lanes = jnp.arange(tblk.shape[0], dtype=tblk.dtype)
+            w["tab"] = _window(tab_blocks, lanes * n_tb, tblk)
+            w["tab_base"] = tblk * TB
+        return w
+
+    def _pick(win, off, fill):
+        """``win[off]`` of every lane by a one-hot select along the window
+        (an index into it inside the step would gather every iteration)."""
+        hit = jnp.arange(win.shape[0])[:, None] == off[None, :]
+        return jnp.where(hit, win, fill).max(axis=0)
+
+    def _draw(w, cur):
+        return _pick(w["tab"], jnp.minimum(cur, TW - 1) - w["tab_base"],
+                     -jnp.inf)
+
+    def _body(s, w, kc):
         s = dict(s, n_iters=s["n_iters"] + ~s["finished"])
-        s, tmp = jax.vmap(_pop_one, in_axes=(0, 0, None))(s, kc, bk)
+        col = jnp.minimum(s["cursor"], width - 1) - w["ev_base"]
+        have = s["cursor"] < kc["n_ev"]
+        rd = {"t_tr": jnp.where(have, _pick(w["times"], col, -jnp.inf),
+                                jnp.inf),
+              "k_tr": jnp.where(have, _pick(w["kinds"], col, _I32_MIN), -1),
+              "w_ev": jnp.where(have, _pick(w["wins"], col, -jnp.inf), -1.0),
+              "u": _draw(w, s["cur"])}
+        s, tmp = jax.vmap(_pop_one)(s, kc, rd)
         # In-window fault date, guarded against FMA contraction (see
         # `_pop_one`): the runtime zero (now - now; unfoldable, now could
         # be non-finite for all the compiler knows) caps the product in
@@ -648,13 +716,35 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
         s = _push_all(s, tmp["push"], fd)
         if has_adaptive:
             s = _fixup(s, kc)
-        s = jax.vmap(_arrive_one, in_axes=(0, 0))(s, kc)
+        s = jax.vmap(_arrive_one)(s, kc, _draw(w, s["cur"]))
         return _advance(s, kc)
 
     def _loop(state, kc, bk):
-        return lax.while_loop(
-            lambda s: ~(jnp.all(s["finished"]) | jnp.any(s["overflow"])),
-            lambda s: _body(s, kc, bk), state)
+        """Blocks of at most B iterations, each after a refill, with the
+        one exit test: the iterations are those of a plain loop."""
+        def running(s):
+            return ~(jnp.all(s["finished"]) | jnp.any(s["overflow"]))
+
+        bk_blocks = {name: _blocked(a, n_eb, B) for name, a in bk.items()}
+        tab_blocks = None if tab_whole else _blocked(kc["tab"], n_tb, TB)
+        w0 = jax.tree_util.tree_map(
+            lambda x: jnp.zeros(x.shape, x.dtype),
+            jax.eval_shape(_refill, state, kc, bk_blocks, tab_blocks))
+        if tab_whole:
+            w0.update(tab=kc["tab"].T, tab_base=0)
+
+        def block(carry):
+            s, w = carry
+            with jax.named_scope("event_refill"):
+                w = dict(w, **_refill(s, kc, bk_blocks, tab_blocks))
+            s = dict(s, n_refills=s["n_refills"] + 1)
+            _, s = lax.while_loop(
+                lambda c: (c[0] < B) & running(c[1]),
+                lambda c: (c[0] + 1, _body(c[1], w, kc)), (0, s))
+            return s, w
+
+        return lax.while_loop(lambda c: running(c[0]), block,
+                              (state, w0))[0]
 
     if mesh is None:
         return jax.jit(_loop, donate_argnums=0), holder
@@ -806,7 +896,8 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         mesh = bank_to = None
     with reg.timer("jax.bank_put_s"):
         bank_dev = jax.device_put(bank_arrs, bank_to)
-    static = (impl, c, cp, d, r, time_base, width, TW, K, has_adaptive, mesh)
+    static = (impl, c, cp, d, r, time_base, width, TW, K, _EV_BLOCK,
+              has_adaptive, mesh)
 
     # -- chunk driver --------------------------------------------------------
     def _init_chunk(sl: slice, n_real: int):
@@ -861,7 +952,7 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
             "n_silent": np.zeros(n, i4),
             "n_verifications": np.zeros(n, i4),
             "n_deep_rollbacks": np.zeros(n, i4),
-            "n_iters": np.zeros(n, i4),
+            "n_iters": np.zeros(n, i4), "n_refills": np.zeros(n, i4),
         }
         state["finished"][n_real:] = True
         kc = {
@@ -939,6 +1030,9 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         reg.count("jax.loop_iters", int(loops.sum()))
         reg.count("jax.lane_iters", int(iters[:n_real].sum()))
         reg.count("jax.lane_slots", int(loops.sum()) * (CL // n_shards))
+        # Every lane of a shard counts each refill of its staged windows.
+        reg.count("jax.event_refills", int(
+            final["n_refills"].reshape(n_shards, -1).max(axis=1).sum()))
         # Periodic checkpoints of real lanes, and the lanes whose job end
         # the last-period flag decided below the scalar test's threshold
         # (0 in float64; on emulated float64, the lanes that test would

@@ -39,6 +39,7 @@ Metric names used by the instrumented call sites:
 ``jax.loop_iters``                      while-loop iterations, per shard
 ``jax.lane_iters``                      iterations real lanes worked
 ``jax.lane_slots``                      iterations x lanes, padding too
+``jax.event_refills``                   staged-window refills, per shard
 ``jax.lane_ckpts``                      periodic checkpoints of real lanes
 ``jax.job_end_slack_lanes``             job ends the last-period flag
                                         decided below ``time_base - 1e-9``
