@@ -7,12 +7,9 @@ the same pop / arrival / lockstep-schedule step as the NumPy engine in
   * a **vmapped per-lane step** for the event pop and event arrival
     sections (each lane is a small scalar program over its own state and
     deferred-fault slots; ``jax.vmap`` lifts it over the lane axis), and
-  * the **event-advance kernel** (:mod:`repro.kernels.event_step`) for
-    the hot schedule step that touches every lane each iteration — a pure
-    ``jnp`` reference by default, or the Pallas kernel in interpret mode
-    (``REPRO_JAX_PALLAS=interpret``).  The compiled kernel
-    (``REPRO_JAX_PALLAS=1``) is refused up front: its tiles would be
-    float64, which Pallas on the TPU does not have.
+  * the **advance step** (:func:`_advance_step`), the schedule step that
+    touches every lane each iteration: plain elementwise ``jnp`` over the
+    lane-state dict.
 
 Feature parity with the NumPy engine is complete: all four standard trust
 policies, exact/inexact windows, per-event window tensors
@@ -61,9 +58,8 @@ there the results agree with the numpy lanes within
 
 The process keeps the lane loops it compiled (the last
 ``_PROGRAMS_MAX``), keyed by everything a program depends on: the
-event-step kernel, the platform's constants, ``cp`` and ``time_base``,
-the adaptive re-plan step, the device mesh, and the shapes and dtypes of
-the loop's arguments (chunk size, event width, table width).  So each key
+platform's constants, ``cp`` and ``time_base``, the adaptive re-plan
+step, the device mesh, and the shapes and dtypes of the loop's arguments (chunk size, event width, table width).  So each key
 compiles once per process, and a later call with an equal key runs that
 executable with no lowering and no compile (``jax.exec_reuses``); reuse
 bank sizes across calls to keep the key.
@@ -184,18 +180,187 @@ def _f64(words) -> np.ndarray:
         .reshape(-1).copy()
 
 
-def _resolve_impl() -> str:
-    """Event-step kernel implementation from ``REPRO_JAX_PALLAS``."""
-    v = os.environ.get("REPRO_JAX_PALLAS", "").strip().lower()
-    if v in ("", "0", "off", "ref"):
-        return "ref"
-    if v in ("interpret", "interpreter"):
-        return "pallas_interpret"
-    if v in ("1", "compile", "tpu", "pallas"):
-        from repro.kernels.event_step import check_compiled_dtype
-        check_compiled_dtype(np.float64)   # the lane state is float64
-        return "pallas"
-    raise ValueError(f"unknown REPRO_JAX_PALLAS value {v!r}")
+def _advance_step(s: dict, kc: dict, *, c: float, cp: float, d: float,
+                  r: float, time_base: float) -> dict:
+    """One schedule step of every lane: ``s`` with the entries it changes.
+
+    Mirrors ``_Machine.advance_to``'s loop body / the NumPy engine's
+    advance passes: work chunks stop at the event target, the in-window
+    proactive cadence and the window end; completed phases run their
+    ``_complete_phase`` transitions.  Lanes with ``now >= target`` (or
+    finished) come back unchanged, so padding lanes are inert.  ``kc``
+    gives the static per-lane knobs (``wwp``, ``vcost``, ``nv``, ``keep``).
+
+    The job ends at the checkpoint of a period flagged ``last_period``, or
+    of any period whose save reaches ``time_base - 1e-9`` (the scalar
+    test).  The flag carries the float64 decision onto emulated float64,
+    whose rounding (about 1e-13 relative) can leave ``saved`` a few 1e-7 s
+    short of ``time_base`` after the last period.  The flagged period still
+    ends exactly: its last work chunk takes ``w_rem`` from itself, which is
+    0 in any arithmetic, and its checkpoint follows.
+    """
+    import jax.numpy as jnp
+
+    fin_thresh = time_base - 1e-9
+    now, target, phase = s["now"], s["target"], s["phase"]
+    finished = s["finished"]
+    phase_end, win_end, win_rem = s["phase_end"], s["win_end"], s["win_rem"]
+    v_wp, v_rem, saved_clean = s["v_wp"], s["v_rem"], s["saved_clean"]
+    vcost, nv, keep = kc["vcost"], kc["nv"], kc["keep"]
+    verify_on = nv >= 1
+    corrupted, vtc = s["corrupted"], s["verify_then_ckpt"]
+    n_dirty, last = s["n_dirty"], s["last_period"]
+
+    adv = ~finished & (now < target)
+    in_work = adv & (phase == _WORK)
+    wz = in_work & (s["w_rem"] <= 0.0)      # degenerate: straight to save
+    wz_v = wz & verify_on
+    phase = jnp.where(wz_v, _VERIFY, jnp.where(wz, _CKPT, phase))
+    phase_end = jnp.where(wz, now + jnp.where(verify_on, vcost, c),
+                          phase_end)
+    vtc = jnp.where(wz_v, True, vtc)
+
+    ww = in_work & ~wz
+    in_win = ww & (now < win_end)
+    dt = jnp.minimum(s["w_rem"], target - now)
+    dt = jnp.minimum(dt, v_rem)
+    cap = jnp.where(in_win, jnp.minimum(win_rem, win_end - now), jnp.inf)
+    dt = jnp.minimum(dt, cap)
+    now = jnp.where(ww, now + dt, now)
+    done = jnp.where(ww, s["done"] + dt, s["done"])
+    w_rem = jnp.where(ww, s["w_rem"] - dt, s["w_rem"])
+    v_rem = jnp.where(ww, v_rem - dt, v_rem)
+    win_rem = jnp.where(in_win, win_rem - dt, win_rem)
+    fin_work = ww & (w_rem <= 0.0)
+    fw_v = fin_work & verify_on
+    phase = jnp.where(fw_v, _VERIFY, jnp.where(fin_work, _CKPT, phase))
+    phase_end = jnp.where(fin_work, now + jnp.where(verify_on, vcost, c),
+                          phase_end)
+    vtc = jnp.where(fw_v, True, vtc)
+    # Intermediate verification due before the period's work is done.
+    vdue = ww & (w_rem > 0.0) & (v_rem <= 0.0)
+    phase = jnp.where(vdue, _VERIFY, phase)
+    phase_end = jnp.where(vdue, now + vcost, phase_end)
+    vtc = jnp.where(vdue, False, vtc)
+    live = ww & (w_rem > 0.0) & (v_rem > 0.0) & in_win
+    # In-window proactive checkpoint due.
+    pro = live & (win_rem <= 0.0) & (now < win_end)
+    phase = jnp.where(pro, _PROCKPT, phase)
+    phase_end = jnp.where(pro, now + cp, phase_end)
+    # Window elapsed without a fault: back to the periodic schedule.
+    closed = live & (now >= win_end)
+    win_end = jnp.where(closed, -jnp.inf, win_end)
+    win_rem = jnp.where(closed, jnp.inf, win_rem)
+
+    in_ph = adv & (phase != _WORK) & ~wz & ~ww   # just-started ckpts wait
+    complete = in_ph & (phase_end <= target)
+    now = jnp.where(complete, phase_end, now)
+    ph0 = phase
+    ck = complete & (ph0 == _CKPT)
+    n_ckpts = s["n_periodic_ckpts"] + ck
+    time_ckpt = s["time_ckpt"] + jnp.where(ck, c, 0.0)
+    saved = jnp.where(ck, done, s["saved"])
+
+    pk = complete & (ph0 == _PROCKPT)
+    n_prockpts = s["n_prockpts"] + pk
+    time_prockpt = s["time_prockpt"] + jnp.where(pk, cp, 0.0)
+    saved = jnp.where(pk, done, saved)
+
+    # Retained-checkpoint ring update (shared by periodic + proactive
+    # saves): a corrupted save is dirty — once the ring holds only dirty
+    # snapshots the newest clean state is the job start.
+    sv = ck | pk
+    dirty_save = sv & corrupted
+    n_dirty = n_dirty + dirty_save
+    saved_clean = jnp.where(dirty_save & (n_dirty >= keep), 0.0,
+                            saved_clean)
+    clean_save = sv & ~corrupted
+    saved_clean = jnp.where(clean_save, done, saved_clean)
+    n_dirty = jnp.where(clean_save, 0, n_dirty)
+
+    # Final-checkpoint acceptance check: a corrupted lane at the end of
+    # the job detects instead of finishing.
+    at_end = ck & (last | (saved >= fin_thresh))
+    det_ck = at_end & corrupted
+    fin = at_end & ~corrupted
+    finished = finished | fin
+    act = ck & (now < win_end)
+    win_rem = jnp.where(act, kc["wwp"], win_rem)
+
+    period_start = jnp.where(pk, now, s["period_start"])
+    phase = jnp.where(pk, _WORK, phase)
+    phase_end = jnp.where(pk, jnp.inf, phase_end)
+    v_rem = jnp.where(pk, v_wp, v_rem)
+    act = pk & (now < win_end)
+    win_rem = jnp.where(act, kc["wwp"], win_rem)
+
+    vf = complete & (ph0 == _VERIFY)
+    time_verify = s["time_verify"] + jnp.where(vf, vcost, 0.0)
+    n_verifs = s["n_verifications"] + vf
+    det_vf = vf & corrupted
+    ok = vf & ~corrupted
+    v_rem = jnp.where(ok, v_wp, v_rem)
+    tc = ok & vtc
+    phase = jnp.where(tc, _CKPT, phase)
+    phase_end = jnp.where(tc, now + c, phase_end)
+    wk = ok & ~vtc
+    phase = jnp.where(wk, _WORK, phase)
+    phase_end = jnp.where(wk, jnp.inf, phase_end)
+
+    dn = complete & (ph0 == _DOWN)
+    time_down = s["time_down"] + jnp.where(dn, d, 0.0)
+    time_downtime = s["time_downtime"] + jnp.where(dn, d, 0.0)
+    phase = jnp.where(dn, _RECOVER, phase)
+    phase_end = jnp.where(dn, now + r, phase_end)
+    rc = complete & (ph0 == _RECOVER)
+    time_down = time_down + jnp.where(rc, r, 0.0)
+    time_recovery = s["time_recovery"] + jnp.where(rc, r, 0.0)
+
+    renew = (ck & ~at_end) | rc
+    phase = jnp.where(renew, _WORK, phase)
+    phase_end = jnp.where(renew, jnp.inf, phase_end)
+    period_start = jnp.where(renew, now, period_start)
+    wpp = jnp.where(renew, jnp.maximum(1e-9, s["period"] - c), s["wpp"])
+    rest = time_base - saved
+    w_rem = jnp.where(renew, jnp.minimum(wpp, rest), w_rem)
+    last = jnp.where(renew, rest <= wpp, last)
+    v_wp = jnp.where(renew & verify_on,
+                     wpp / jnp.maximum(nv, 1).astype(wpp.dtype), v_wp)
+    v_rem = jnp.where(renew, v_wp, v_rem)
+
+    # Late detection (verify completion, or the final acceptance check,
+    # while corrupted): roll back past every dirty snapshot to the newest
+    # clean one, paying R only.
+    det = det_ck | det_vf
+    lost = done - saved_clean
+    time_lost = s["time_lost"] + jnp.where(det, lost, 0.0)
+    n_rolls = s["n_rollbacks"] + (det & (lost > 0.0))
+    n_deep = s["n_deep_rollbacks"] + (det & (n_dirty > 0))
+    done = jnp.where(det, saved_clean, done)
+    saved = jnp.where(det, saved_clean, saved)
+    n_dirty = jnp.where(det, 0, n_dirty)
+    corrupted = corrupted & ~det
+    phase = jnp.where(det, _RECOVER, phase)
+    phase_end = jnp.where(det, now + r, phase_end)
+    win_end = jnp.where(det, -jnp.inf, win_end)
+    win_rem = jnp.where(det, jnp.inf, win_rem)
+
+    stall = in_ph & ~complete
+    now = jnp.where(stall, target, now)
+
+    return dict(s, now=now, done=done, saved=saved,
+                period_start=period_start, phase_end=phase_end, wpp=wpp,
+                w_rem=w_rem, win_end=win_end, win_rem=win_rem,
+                time_ckpt=time_ckpt, time_prockpt=time_prockpt,
+                time_down=time_down, time_downtime=time_downtime,
+                time_recovery=time_recovery, time_lost=time_lost,
+                time_verify=time_verify, v_wp=v_wp, v_rem=v_rem,
+                saved_clean=saved_clean, phase=phase, finished=finished,
+                n_periodic_ckpts=n_ckpts, n_prockpts=n_prockpts,
+                n_rollbacks=n_rolls, n_verifications=n_verifs,
+                n_deep_rollbacks=n_deep, n_dirty=n_dirty,
+                corrupted=corrupted, verify_then_ckpt=vtc,
+                last_period=last)
 
 
 class _Program(NamedTuple):
@@ -261,7 +426,7 @@ def _lane_program(static: tuple, args: tuple, reg) -> _Program:
     return prog
 
 
-def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
+def _build_loop(c, cp, d, r, time_base, width: int, TW: int,
                 K: int, B: int, has_adaptive: bool, mesh, args: tuple):
     """The lane loop, jitted and not yet lowered, and the holder its host
     callback reads (None without adaptive lanes).
@@ -279,18 +444,6 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
     import jax
     import jax.numpy as jnp
     from jax import lax
-
-    from repro.kernels.event_step import (F_DONE, F_NOW, F_PERIOD, F_PHEND,
-                                          F_PSTART, F_SAVED, F_SVCLEAN,
-                                          F_TARGET, F_TCKPT, F_TDOWN,
-                                          F_TDOWNT, F_TLOST, F_TPROC,
-                                          F_TRECOV, F_TVERIFY, F_VREM,
-                                          F_VWP, F_WINEND, F_WINREM, F_WPP,
-                                          F_WREM, F_WWP, I_CORR, I_FIN,
-                                          I_LAST, I_NCKPT, I_NDEEP,
-                                          I_NDIRTY, I_NPROC, I_NROLL,
-                                          I_NVERIF, I_PHASE, I_VTC,
-                                          event_step)
 
     # -- per-lane step: event pop -------------------------------------------
     def _push_one(def_time, def_seq, next_seq, overflow, push, date):
@@ -595,42 +748,6 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
                     overflow=overflow)
 
     # -- the loop body -------------------------------------------------------
-    def _advance(s, kc):
-        fs = jnp.stack([s["now"], s["done"], s["saved"], s["period_start"],
-                        s["phase_end"], s["wpp"], s["w_rem"], s["win_end"],
-                        s["win_rem"], s["target"], s["time_ckpt"],
-                        s["time_prockpt"], s["time_down"], s["period"],
-                        kc["wwp"], s["time_downtime"], s["time_recovery"],
-                        s["time_lost"], s["time_verify"], s["v_wp"],
-                        s["v_rem"], kc["vcost"], s["saved_clean"]])
-        is_ = jnp.stack([s["phase"], s["finished"].astype(jnp.int32),
-                         s["n_periodic_ckpts"], s["n_prockpts"],
-                         s["n_rollbacks"], s["n_verifications"],
-                         s["n_deep_rollbacks"], s["n_dirty"],
-                         s["corrupted"].astype(jnp.int32),
-                         s["verify_then_ckpt"].astype(jnp.int32),
-                         kc["nv"], kc["keep"],
-                         s["last_period"].astype(jnp.int32)])
-        for _ in range(_ADV_PASSES):
-            fs, is_ = event_step(fs, is_, c=c, cp=cp, d=d, r=r,
-                                 time_base=time_base, impl=impl)
-        return dict(s, now=fs[F_NOW], done=fs[F_DONE], saved=fs[F_SAVED],
-                    period_start=fs[F_PSTART], phase_end=fs[F_PHEND],
-                    wpp=fs[F_WPP], w_rem=fs[F_WREM], win_end=fs[F_WINEND],
-                    win_rem=fs[F_WINREM], time_ckpt=fs[F_TCKPT],
-                    time_prockpt=fs[F_TPROC], time_down=fs[F_TDOWN],
-                    time_downtime=fs[F_TDOWNT], time_recovery=fs[F_TRECOV],
-                    time_lost=fs[F_TLOST], time_verify=fs[F_TVERIFY],
-                    v_wp=fs[F_VWP], v_rem=fs[F_VREM],
-                    saved_clean=fs[F_SVCLEAN],
-                    phase=is_[I_PHASE], finished=is_[I_FIN] != 0,
-                    n_periodic_ckpts=is_[I_NCKPT], n_prockpts=is_[I_NPROC],
-                    n_rollbacks=is_[I_NROLL], n_verifications=is_[I_NVERIF],
-                    n_deep_rollbacks=is_[I_NDEEP], n_dirty=is_[I_NDIRTY],
-                    corrupted=is_[I_CORR] != 0,
-                    verify_then_ckpt=is_[I_VTC] != 0,
-                    last_period=is_[I_LAST] != 0)
-
     def _push_all(s, push, date):
         """Full-array deferred-fault insert (the pop-site pushes)."""
         empty = jnp.isinf(s["def_time"])
@@ -717,7 +834,10 @@ def _build_loop(impl: str, c, cp, d, r, time_base, width: int, TW: int,
         if has_adaptive:
             s = _fixup(s, kc)
         s = jax.vmap(_arrive_one)(s, kc, _draw(w, s["cur"]))
-        return _advance(s, kc)
+        for _ in range(_ADV_PASSES):
+            s = _advance_step(s, kc, c=c, cp=cp, d=d, r=r,
+                              time_base=time_base)
+        return s
 
     def _loop(state, kc, bk):
         """Blocks of at most B iterations, each after a refill, with the
@@ -777,7 +897,6 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                   chunk: int | None = None) -> dict[str, Any]:
     import jax
 
-    impl = _resolve_impl()
     if not jax.config.jax_enable_x64:
         raise RuntimeError(
             "the jax backend needs float64 state for the scalar-equivalence "
@@ -896,8 +1015,8 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         mesh = bank_to = None
     with reg.timer("jax.bank_put_s"):
         bank_dev = jax.device_put(bank_arrs, bank_to)
-    static = (impl, c, cp, d, r, time_base, width, TW, K, _EV_BLOCK,
-              has_adaptive, mesh)
+    static = (c, cp, d, r, time_base, width, TW, K, _EV_BLOCK, has_adaptive,
+              mesh)
 
     # -- chunk driver --------------------------------------------------------
     def _init_chunk(sl: slice, n_real: int):

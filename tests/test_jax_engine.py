@@ -1,4 +1,4 @@
-"""Flagship jax engine suite: feature parity, scale paths, kernel impls.
+"""Flagship jax engine suite: feature parity and scale paths.
 
 Every test here asserts the engines' **bit-for-bit equivalence contract**:
 the jax lane engine replays the exact float64 operation sequence of the
@@ -6,7 +6,7 @@ NumPy lane engine (itself pinned to the scalar reference), so results are
 compared with ``==`` — never ``allclose`` — across the full candidate
 matrix (all four trust families x instant/within window modes x per-event
 windows x adaptive re-planning incl. online-mu and the exact model) and
-across every execution plan (chunked, sharded, Pallas-interpreted).
+across every execution plan (chunked, sharded).
 
 The contract needs float64, so the whole module skips unless x64 is on —
 run it as ``JAX_ENABLE_X64=1 python -m pytest tests/test_jax_engine.py``
@@ -135,7 +135,7 @@ def test_adaptive_mu_within_window_combo():
 
 
 # ---------------------------------------------------------------------------
-# Execution plans: chunking, sharding, Pallas — same bits, different plan
+# Execution plans: chunking, sharding — same bits, different plan
 # ---------------------------------------------------------------------------
 
 def test_chunked_matches_unchunked(monkeypatch):
@@ -167,46 +167,6 @@ def test_adaptive_chunked_matches(monkeypatch):
     ref = _run(traces, "jax", **kw)
     monkeypatch.setenv("REPRO_JAX_CHUNK", "4")
     _assert_bitwise(ref, _run(traces, "jax", **kw), "adaptive chunk=4")
-
-
-def test_pallas_interpret_matches(monkeypatch):
-    """The Pallas event-step kernel (interpreter mode on CPU) is drop-in
-    for the jnp reference inside the engine loop."""
-    traces = _traces()
-    kw = dict(trust=ThresholdTrust(100.0), inexact_window=300.0)
-    ref = _run(traces, "jax", **kw)
-    monkeypatch.setenv("REPRO_JAX_PALLAS", "interpret")
-    _assert_bitwise(ref, _run(traces, "jax", **kw), "pallas interpret")
-
-
-def test_event_step_pallas_interpret_matches_ref():
-    """Direct kernel check: the Pallas event-step (interpreter mode) is
-    bitwise identical to the jnp reference on arbitrary stacked state,
-    including a lane count that is not a multiple of the block size."""
-    import jax.numpy as jnp
-    from repro.kernels.event_step import N_F, N_I, event_step
-
-    r = np.random.default_rng(0)
-    n = 300
-    fs = jnp.asarray(r.uniform(0.0, 5000.0, (N_F, n)))
-    is_ = jnp.asarray(
-        np.stack([r.integers(0, 5, n), r.integers(0, 2, n)]
-                 + [r.integers(0, 40, n) for _ in range(N_I - 2)]
-                 ).astype(np.int32))
-    assert is_.shape == (N_I, n)    # phase/finished, then the counters
-    kw = dict(c=60.0, cp=30.0, d=10.0, r=30.0, time_base=120000.0)
-    f_ref, i_ref = event_step(fs, is_, impl="ref", **kw)
-    f_pl, i_pl = event_step(fs, is_, impl="pallas_interpret", **kw)
-    assert (np.asarray(f_ref) == np.asarray(f_pl)).all()
-    assert (np.asarray(i_ref) == np.asarray(i_pl)).all()
-    with pytest.raises(ValueError, match="impl"):
-        event_step(fs, is_, impl="cuda", **kw)
-
-
-def test_pallas_env_rejects_garbage(monkeypatch):
-    monkeypatch.setenv("REPRO_JAX_PALLAS", "gpu?!")
-    with pytest.raises(ValueError, match="REPRO_JAX_PALLAS"):
-        _run(_traces(), "jax", trust=NeverTrust())
 
 
 def test_deferred_overflow_raises():

@@ -10,7 +10,7 @@ chip's emulated float64 leaves ``saved`` a few 1e-7 s short of
 The engine needs float64, which this suite runs without, so one
 subprocess with ``JAX_ENABLE_X64=1`` on the CPU runs every case once and
 prints what it saw as JSON; the tests read that.  Cases: single
-checkpoints through the event step, and the paper's section 5 platforms
+checkpoints through the advance step, and the paper's section 5 platforms
 (``bench/configs``) at a small size, generated through ``ScenarioSpec``,
 through ``evaluate_strategies(engine="jax")`` against the scalar oracle.
 """
@@ -33,11 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.batch_jax import _advance_step
 from repro.core.policies import Strategy
-from repro.core.simulator import ThresholdTrust, simulate
+from repro.core.simulator import _CKPT, ThresholdTrust, simulate
 from repro.experiments.runner import EvalCache, evaluate_strategies, trace_bank
 from repro.experiments.spec import ScenarioSpec
-from repro.kernels import event_step as ev
 from repro.obs.metrics import MetricsRegistry, set_registry
 
 CKPT_CASES = json.loads(sys.argv[2])
@@ -47,22 +47,30 @@ TIME_BASE, C, WPP = 4812011.71875, 600.0, 3000.0
 def checkpoint(last, left):
     # One lane at the end of a periodic checkpoint: its work done stands
     # ``left`` seconds short of the job, and the step completes the save.
-    fs = np.zeros(ev.N_F)
-    is_ = np.zeros(ev.N_I, np.int64)
     now = 3.0e7
-    fs[[ev.F_NOW, ev.F_PHEND, ev.F_TARGET]] = now, now + C, now + 2 * C
-    fs[[ev.F_DONE, ev.F_SAVED]] = TIME_BASE - left, TIME_BASE - left - WPP
-    fs[[ev.F_PERIOD, ev.F_WPP, ev.F_WREM]] = WPP + C, WPP, 0.0
-    fs[[ev.F_WINEND, ev.F_WINREM]] = -np.inf, np.inf
-    fs[[ev.F_WWP, ev.F_VWP, ev.F_VREM]] = np.inf, np.inf, np.inf
-    is_[[ev.I_PHASE, ev.I_KEEP, ev.I_LAST]] = 1, 1, last   # _CKPT
-    out_f, out_i = ev.event_step(jnp.asarray(fs[:, None]),
-                                 jnp.asarray(is_[:, None], jnp.int32),
-                                 c=C, cp=C, d=60.0, r=C, time_base=TIME_BASE)
-    out_f, out_i = np.asarray(out_f)[:, 0], np.asarray(out_i)[:, 0]
-    return {"finished": int(out_i[ev.I_FIN]), "phase": int(out_i[ev.I_PHASE]),
-            "last": int(out_i[ev.I_LAST]), "ckpts": int(out_i[ev.I_NCKPT]),
-            "w_rem": float(out_f[ev.F_WREM]), "saved": float(out_f[ev.F_SAVED])}
+    f8 = dict.fromkeys(("period_start", "saved_clean", "time_ckpt",
+                        "time_prockpt", "time_down", "time_downtime",
+                        "time_recovery", "time_lost", "time_verify"), 0.0)
+    f8.update(now=now, phase_end=now + C, target=now + 2 * C,
+              done=TIME_BASE - left, saved=TIME_BASE - left - WPP,
+              period=WPP + C, wpp=WPP, w_rem=0.0, win_end=-np.inf,
+              win_rem=np.inf, v_wp=np.inf, v_rem=np.inf)
+    i4 = dict.fromkeys(("n_periodic_ckpts", "n_prockpts", "n_rollbacks",
+                        "n_verifications", "n_deep_rollbacks", "n_dirty"), 0)
+    i4.update(phase=_CKPT)
+    s = {k: jnp.full(1, v, jnp.float64) for k, v in f8.items()}
+    s.update({k: jnp.full(1, v, jnp.int32) for k, v in i4.items()})
+    s.update({k: jnp.full(1, v, bool) for k, v in (
+        ("finished", False), ("corrupted", False),
+        ("verify_then_ckpt", False), ("last_period", bool(last)))})
+    kc = {"wwp": jnp.full(1, np.inf), "vcost": jnp.zeros(1),
+          "nv": jnp.zeros(1, jnp.int32), "keep": jnp.ones(1, jnp.int32)}
+    out = _advance_step(s, kc, c=C, cp=C, d=60.0, r=C, time_base=TIME_BASE)
+    out = {k: np.asarray(v)[0] for k, v in out.items()}
+    return {"finished": int(out["finished"]), "phase": int(out["phase"]),
+            "last": int(out["last_period"]),
+            "ckpts": int(out["n_periodic_ckpts"]),
+            "w_rem": float(out["w_rem"]), "saved": float(out["saved"])}
 
 
 def platform_case(name):

@@ -157,44 +157,15 @@ def test_quantize_zero_delta():
 
 # -- compiled-kernel dispatch -------------------------------------------------
 
-def test_compiled_event_step_refuses_float64_tiles():
-    """Pallas on the TPU has no float64: the compiled event-step kernel
-    refuses the engine's float64 lane state while tracing, naming the
-    tiles, instead of failing inside the Mosaic lowering."""
-    from repro.kernels.event_step import N_F, N_I, event_step_pallas
-    kw = dict(c=60.0, cp=30.0, d=10.0, r=30.0, time_base=120000.0)
-    with jax.enable_x64(True):
-        fs = jax.ShapeDtypeStruct((N_F, 256), jnp.float64)
-        is_ = jax.ShapeDtypeStruct((N_I, 256), jnp.int32)
-        with pytest.raises(NotImplementedError, match="float64"):
-            jax.jit(lambda a, b: event_step_pallas(a, b, **kw)).lower(fs, is_)
-
-
-@pytest.mark.parametrize("value", ["1", "compile", "pallas"])
-def test_repro_jax_pallas_compiled_refused_up_front(monkeypatch, value):
-    """REPRO_JAX_PALLAS=1 is refused before the engine does any work."""
-    from repro.core.batch import simulate_batch
-    from repro.core.traces import Exponential, make_event_trace
-    from repro.core.waste import Platform
-    monkeypatch.setenv("REPRO_JAX_PALLAS", value)
-    tr = make_event_trace(Exponential(1.0), 2500.0, 0.7, 0.6, 4e5,
-                          np.random.default_rng(0))
-    with pytest.raises(NotImplementedError, match="float64"):
-        simulate_batch([tr], Platform(mu=2500.0, c=60.0, d=10.0, r=30.0),
-                       1.2e5, [1200.0], backend="jax")
-
-
 def test_kernel_entry_points_compile_by_default():
     """Interpret mode happens only where a caller asks for it by name."""
     import inspect
 
-    from repro.kernels import (ckpt_delta, decode_attention, event_step,
-                               flash_attention)
+    from repro.kernels import ckpt_delta, decode_attention, flash_attention
     for fn in (ckpt_delta.quantize_delta_pallas.__wrapped__,
                ckpt_delta.dequantize_delta_pallas,
                flash_attention.flash_attention_pallas.__wrapped__,
-               decode_attention.decode_attention_pallas.__wrapped__,
-               event_step.event_step_pallas.__wrapped__):
+               decode_attention.decode_attention_pallas.__wrapped__):
         assert inspect.signature(fn).parameters["interpret"].default is False
 
 

@@ -87,7 +87,7 @@ res["again"] = case()
 res["other_c"] = case(plat=other)
 res["other_cp"] = case(cp=40.0)
 res["other_time_base"] = case(time_base=100000.0)
-res["interpret"] = case(env={"REPRO_JAX_PALLAS": "interpret"})
+res["other_d"] = case(plat=Platform(mu=2500.0, c=60.0, d=20.0, r=30.0))
 res["sharded"] = case(env={"REPRO_JAX_SHARD": "1"})
 res["chunked"] = case(env={"REPRO_JAX_CHUNK": "4"})
 res["adaptive"] = case(adaptive=AD)
@@ -107,7 +107,7 @@ print("LANE-PROGRAMS " + json.dumps(res))
 """
 
 # Calls whose program no earlier call of the process had.
-COMPILED = ["first", "other_c", "other_cp", "other_time_base", "interpret",
+COMPILED = ["first", "other_c", "other_cp", "other_time_base", "other_d",
             "sharded", "chunked", "adaptive", "chunk_2", "evicted"]
 # Calls equal in program to an earlier one, with equal lane shapes.
 REUSED = ["again", "adaptive_tol", "adaptive_exact", "adaptive_mu"]

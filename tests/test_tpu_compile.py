@@ -21,12 +21,24 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.batch_jax import _advance_step
 from repro.kernels import ckpt_delta
-from repro.kernels.event_step import N_F, N_I, event_step
 
 # An xlstm-125m embedding leaf (vocab x d_model) and a 2048 x 8192 leaf.
 DELTA_SHAPES = [(50304, 768), (2048, 8192)]
 STEP_KW = dict(c=600.0, cp=600.0, d=60.0, r=600.0, time_base=4.8e6)
+# The lane state the advance step reads, and its per-lane knobs, by dtype.
+STEP_STATE = {
+    jnp.float64: ("now", "target", "phase_end", "done", "saved",
+                  "saved_clean", "period_start", "period", "wpp", "w_rem",
+                  "win_end", "win_rem", "v_wp", "v_rem", "time_ckpt",
+                  "time_prockpt", "time_down", "time_downtime",
+                  "time_recovery", "time_lost", "time_verify"),
+    jnp.int32: ("phase", "n_periodic_ckpts", "n_prockpts", "n_rollbacks",
+                "n_verifications", "n_deep_rollbacks", "n_dirty"),
+    jnp.bool_: ("finished", "corrupted", "verify_then_ckpt", "last_period"),
+}
+STEP_KNOBS = {jnp.float64: ("wwp", "vcost"), jnp.int32: ("nv", "keep")}
 
 
 @pytest.fixture(scope="module")
@@ -64,17 +76,20 @@ def test_topology_is_a_v5e(topo):
 
 
 @pytest.mark.parametrize("lanes", [4800, 65536])
-def test_event_step_ref_compiles_float64(one_chip, lanes):
-    """The engine's default event step, on float64 lane state (emulated
-    by the TPU), at the paper grid's 4,800 lanes and a 2^16 chunk."""
-    step = functools.partial(event_step, impl="ref", **STEP_KW)
+def test_advance_step_compiles_float64(one_chip, lanes):
+    """The engine's advance step, on float64 lane state (emulated by the
+    TPU), at the paper grid's 4,800 lanes and a 2^16 chunk."""
+    step = functools.partial(_advance_step, **STEP_KW)
     with jax.enable_x64(True):
-        fs = _struct((N_F, lanes), jnp.float64, one_chip)
-        is_ = _struct((N_I, lanes), jnp.int32, one_chip)
-        compiled = jax.jit(step).lower(fs, is_).compile()
-    out_fs, out_is = compiled.out_info
-    assert out_fs.shape == (N_F, lanes) and out_fs.dtype == jnp.float64
-    assert out_is.shape == (N_I, lanes)
+        def structs(by_dtype):
+            return {k: _struct((lanes,), dt, one_chip)
+                    for dt, keys in by_dtype.items() for k in keys}
+        s = structs(STEP_STATE)
+        compiled = jax.jit(step).lower(s, structs(STEP_KNOBS)).compile()
+    out = compiled.out_info
+    assert out.keys() == s.keys()
+    assert all((out[k].shape, out[k].dtype) == (v.shape, v.dtype)
+               for k, v in s.items())
 
 
 @pytest.mark.parametrize("shape", DELTA_SHAPES)
