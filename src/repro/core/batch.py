@@ -1092,17 +1092,14 @@ def simulate_lanes(
     if lane_trace.size == 0:
         return np.empty(0, dtype=np.float64)
     if backend == "jax":
-        from ..obs.metrics import get_registry
         from .batch_jax import run_lanes_jax
-        with get_registry().timer("lanes.pack_s"):
-            bank = _pack_bank(traces, start)
-        out = run_lanes_jax(bank, platform, time_base, lane_trace,
+        out = run_lanes_jax(traces, platform, time_base, lane_trace,
                             lane_period, lane_kind, lane_param, lane_window,
                             lane_seed, cp, lane_wmode=lane_wmode,
                             lane_wperiod=lane_wperiod,
                             lane_adaptive=lane_adaptive,
                             lane_nverify=lane_nv, lane_vcost=lane_vc,
-                            lane_keep=lane_kc)
+                            lane_keep=lane_kc, start=start)
         return out["makespan"]
     if backend != "numpy":
         raise ValueError(f"unknown backend {backend!r}")
@@ -1191,7 +1188,6 @@ def simulate_batch(
     else:
         seeds = np.asarray(trace_seeds, dtype=np.int64).reshape(n_traces)
 
-    bank = _pack_bank(traces, start)
     # Lane layout: candidate-major, trace-minor -> reshape to the grid.
     lane_trace = np.tile(np.arange(n_traces, dtype=np.int64), n_cand)
     lane_period = np.repeat(period_arr, n_traces)
@@ -1214,13 +1210,13 @@ def simulate_batch(
 
     if backend == "jax":
         from .batch_jax import run_lanes_jax
-        out = run_lanes_jax(bank, platform, time_base, lane_trace,
+        out = run_lanes_jax(traces, platform, time_base, lane_trace,
                             lane_period, lane_kind, lane_param, lane_window,
                             lane_seed, cp, lane_wmode=lane_wmode,
                             lane_wperiod=lane_wperiod,
                             lane_adaptive=lane_adaptive,
                             lane_nverify=lane_nv, lane_vcost=lane_vc,
-                            lane_keep=lane_kc)
+                            lane_keep=lane_kc, start=start)
         shape = (n_cand, n_traces)
         return BatchResult(
             makespan=out["makespan"].reshape(shape), time_base=time_base,
@@ -1253,8 +1249,9 @@ def simulate_batch(
     if backend != "numpy":
         raise ValueError(f"unknown backend {backend!r}")
 
-    st = _run_lanes(bank, platform, time_base, lane_trace, lane_period,
-                    lane_kind, lane_param, lane_window, lane_seed, cp,
+    st = _run_lanes(_pack_bank(traces, start), platform, time_base,
+                    lane_trace, lane_period, lane_kind, lane_param,
+                    lane_window, lane_seed, cp,
                     lane_wmode, lane_wperiod, lane_adaptive,
                     lane_nverify=lane_nv, lane_vcost=lane_vc,
                     lane_keep=lane_kc)

@@ -63,6 +63,13 @@ step, the device mesh, and the shapes and dtypes of the loop's arguments (chunk 
 compiles once per process, and a later call with an equal key runs that
 executable with no lowering and no compile (``jax.exec_reuses``); reuse
 bank sizes across calls to keep the key.
+
+It also keeps the trace banks it placed on the device (the last
+``_BANKS_MAX``), each with private copies of the traces it was packed
+from.  A call whose traces, job start and bank sharding equal an
+entry's, compared element for element, runs on that entry's device
+arrays with no packing and no ``device_put`` (``jax.bank_reuses``): a
+planner that refines its period grid sends its bank once.
 """
 
 from __future__ import annotations
@@ -111,10 +118,26 @@ _ADV_PASSES = 4         # schedule steps per loop iteration (cf. numpy's 6)
 _EV_BLOCK = 64
 
 
-def _draw_tables(bank, lane_trace: np.ndarray, lane_kind: np.ndarray,
+def _draw_counts(bank) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per trace of ``bank``: its prediction events, its true predictions
+    with a positive window of their own, and those with the -1 sentinel
+    (all of them where the bank has no windows).  See `_draw_tables`."""
+    is_true = bank.kinds == FAULT_PRED
+    n_pred = (is_true | (bank.kinds == FALSE_PRED)).sum(axis=1)
+    if bank.windows is None:
+        cnt_own = np.zeros(bank.kinds.shape[0], dtype=np.int64)
+        cnt_fb = is_true.sum(axis=1)
+    else:
+        cnt_own = (is_true & (bank.windows > 0.0)).sum(axis=1)
+        cnt_fb = (is_true & (bank.windows < 0.0)).sum(axis=1)
+    return n_pred, cnt_own, cnt_fb
+
+
+def _draw_tables(counts, lane_trace: np.ndarray, lane_kind: np.ndarray,
                  lane_window: np.ndarray,
                  lane_seed: np.ndarray) -> np.ndarray:
-    """Per-lane stream-prefix tables of pre-drawn uniforms.
+    """Per-lane stream-prefix tables of pre-drawn uniforms, from the
+    bank's `_draw_counts`.
 
     A lane consumes at most one draw per true prediction whose effective
     window is positive (the in-window fault offset) plus one per
@@ -126,14 +149,7 @@ def _draw_tables(bank, lane_trace: np.ndarray, lane_kind: np.ndarray,
     values of the lane's ``default_rng(seed)`` stream bound every draw
     the scalar engine can make, in consumption order.
     """
-    is_true = bank.kinds == FAULT_PRED
-    n_pred = (is_true | (bank.kinds == FALSE_PRED)).sum(axis=1)
-    if bank.windows is None:
-        cnt_own = np.zeros(bank.kinds.shape[0], dtype=np.int64)
-        cnt_fb = is_true.sum(axis=1)
-    else:
-        cnt_own = (is_true & (bank.windows > 0.0)).sum(axis=1)
-        cnt_fb = (is_true & (bank.windows < 0.0)).sum(axis=1)
+    n_pred, cnt_own, cnt_fb = counts
     need = (cnt_own[lane_trace]
             + cnt_fb[lane_trace] * (lane_window > 0.0)
             + n_pred[lane_trace] * (lane_kind == _TRUST_FIXED_Q)
@@ -424,6 +440,87 @@ def _lane_program(static: tuple, args: tuple, reg) -> _Program:
         while len(_PROGRAMS) > _PROGRAMS_MAX:
             _PROGRAMS.popitem(last=False)
     return prog
+
+
+class _Bank(NamedTuple):
+    """A trace bank on the device, and what it was made from."""
+    start: str          # the job start, by its bits (``float.hex``)
+    inputs: tuple       # per trace: copies of its times, kinds, windows
+    sharding: Any       # the device arrays' (None: the default device)
+    host: Any           # the packed `_EventBank`
+    counts: tuple       # its `_draw_counts`
+    dev: dict           # times (f64), kinds (i32), wins (f64, -1: none)
+
+
+# The banks this process placed on the device; the last is the most
+# recently used, and the least recently used leaves first.
+_BANKS: list[_Bank] = []
+_BANKS_MAX = 2
+_BANKS_LOCK = threading.Lock()
+
+
+def _same_array(kept: np.ndarray | None, given) -> bool:
+    """``given`` holds exactly ``kept``'s elements, in its dtype (a NaN
+    is no match); None matches only None."""
+    if kept is None or given is None:
+        return kept is given
+    given = np.asarray(given)
+    return given.dtype == kept.dtype and np.array_equal(given, kept)
+
+
+def _holds(bank: _Bank, traces, start: str, sharding) -> bool:
+    """Whether ``bank`` is the one these traces pack to, placed with
+    ``sharding``: compared by content, since a frozen trace's arrays can
+    still change in place."""
+    if (bank.start != start or bank.sharding != sharding
+            or len(bank.inputs) != len(traces)
+            or any(a.is_deleted() for a in bank.dev.values())):
+        return False
+    return all(_same_array(t, tr.times) and _same_array(k, tr.kinds)
+               and _same_array(w, tr.windows)
+               for (t, k, w), tr in zip(bank.inputs, traces))
+
+
+def _resident_bank(traces, start: float, sharding, reg) -> _Bank:
+    """The bank of ``traces`` from ``start`` on the device, placed with
+    ``sharding``: one this process placed for equal inputs
+    (``jax.bank_reuses``), or packed (``lanes.pack_s``) and put
+    (``jax.bank_put_s``) now."""
+    import jax
+
+    from .batch import _pack_bank
+
+    start_bits = float(start).hex()
+    with reg.timer("jax.bank_check_s"):
+        with _BANKS_LOCK:
+            kept = _BANKS[::-1]
+        found = next((b for b in kept
+                      if _holds(b, traces, start_bits, sharding)), None)
+    if found is not None:
+        reg.count("jax.bank_reuses")
+        with _BANKS_LOCK:
+            rest = [b for b in _BANKS if b is not found]
+            if len(rest) < len(_BANKS):
+                _BANKS[:] = rest + [found]
+        return found
+    with reg.timer("lanes.pack_s"):
+        inputs = tuple((np.array(tr.times), np.array(tr.kinds),
+                        None if tr.windows is None
+                        else np.array(tr.windows)) for tr in traces)
+        host = _pack_bank(traces, start)
+        # The bank enters the loop as an argument (not a closed-over
+        # constant): one copy per device, replicated across a mesh.
+        arrs = {"times": host.times, "kinds": host.kinds.astype(np.int32),
+                "wins": (host.windows if host.windows is not None
+                         else np.full_like(host.times, -1.0))}
+        counts = _draw_counts(host)
+    with reg.timer("jax.bank_put_s"):
+        dev = jax.device_put(arrs, sharding)
+    bank = _Bank(start_bits, inputs, sharding, host, counts, dev)
+    with _BANKS_LOCK:
+        _BANKS.append(bank)
+        del _BANKS[:-_BANKS_MAX]
+    return bank
 
 
 def _build_loop(c, cp, d, r, time_base, width: int, TW: int,
@@ -883,7 +980,7 @@ def _build_loop(c, cp, d, r, time_base, width: int, TW: int,
         donate_argnums=0), holder
 
 
-def run_lanes_jax(bank, platform: Platform, time_base: float,
+def run_lanes_jax(traces, platform: Platform, time_base: float,
                   lane_trace: np.ndarray, lane_period: np.ndarray,
                   lane_kind: np.ndarray, lane_param: np.ndarray,
                   lane_window: np.ndarray, lane_seed: np.ndarray,
@@ -894,7 +991,11 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
                   lane_nverify: np.ndarray | None = None,
                   lane_vcost: np.ndarray | None = None,
                   lane_keep: np.ndarray | None = None,
-                  chunk: int | None = None) -> dict[str, Any]:
+                  chunk: int | None = None,
+                  start: float = 0.0) -> dict[str, Any]:
+    """Run the lanes over ``traces`` from the job start ``start``, lane
+    ``j`` on trace ``lane_trace[j]``, on the bank `_resident_bank` keeps
+    on the device.  See :func:`repro.core.batch.simulate_lanes`."""
     import jax
 
     if not jax.config.jax_enable_x64:
@@ -907,7 +1008,6 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
 
     L = int(lane_trace.size)
     K = _DEF_SLOTS
-    width = bank.times.shape[1]
     c, d, r = platform.c, platform.d, platform.r
 
     lane_period = np.asarray(lane_period, dtype=np.float64).copy()
@@ -975,22 +1075,6 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
     else:
         ad_estmu = np.zeros(L, dtype=bool)
 
-    from repro.obs.metrics import get_registry
-    reg = get_registry()
-    _listen_for_cache_hits()
-    with reg.timer("jax.draw_tables_s"):
-        tab = _draw_tables(bank, lane_trace, lane_kind, lane_window,
-                           lane_seed)
-    TW = tab.shape[1]
-
-    # The trace bank enters the loop as an argument (not a closed-over
-    # constant): one copy per device, replicated across a sharded mesh.
-    bank_arrs = {"times": np.asarray(bank.times, dtype=np.float64),
-                 "kinds": bank.kinds.astype(np.int32),
-                 "wins": (bank.windows if bank.windows is not None
-                          else np.full_like(bank.times, -1.0))}
-    n_ev = bank.n_events[lane_trace].astype(np.int32)
-
     # -- chunking / sharding layout -----------------------------------------
     env_chunk = os.environ.get("REPRO_JAX_CHUNK", "").strip()
     if chunk is None and env_chunk:
@@ -1013,8 +1097,17 @@ def run_lanes_jax(bank, platform: Platform, time_base: float,
         bank_to = NamedSharding(mesh, P())
     else:
         mesh = bank_to = None
-    with reg.timer("jax.bank_put_s"):
-        bank_dev = jax.device_put(bank_arrs, bank_to)
+
+    from repro.obs.metrics import get_registry
+    reg = get_registry()
+    _listen_for_cache_hits()
+    bank = _resident_bank(traces, start, bank_to, reg)
+    bank_dev, width = bank.dev, bank.host.times.shape[1]
+    with reg.timer("jax.draw_tables_s"):
+        tab = _draw_tables(bank.counts, lane_trace, lane_kind, lane_window,
+                           lane_seed)
+    TW = tab.shape[1]
+    n_ev = bank.host.n_events[lane_trace].astype(np.int32)
     static = (c, cp, d, r, time_base, width, TW, K, _EV_BLOCK, has_adaptive,
               mesh)
 

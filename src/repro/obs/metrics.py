@@ -22,9 +22,11 @@ Metric names used by the instrumented call sites:
 ``runner.eval_s``                       strategy-evaluation wall time
 ``runner.gather_s``                     cache lookups + dedup of pairs
 ``runner.collect_s``                    cache puts, duplicates, means
-``lanes.pack_s``                        trace-bank packing (jax path)
+``jax.bank_check_s``                    is the bank already on the device
+``jax.bank_reuses``                     calls run on a bank put earlier
+``lanes.pack_s``                        trace-bank packing, on a miss
+``jax.bank_put_s``                      bank ``device_put``, on a miss
 ``jax.draw_tables_s``                   pre-drawn uniform tables
-``jax.bank_put_s``                      bank ``device_put`` (enqueue)
 ``jax.init_chunk_s``                    host lane state, all chunks
 ``jax.lower_s``                         tracing + lowering the lane loop
 ``jax.xla_compile_s``                   XLA compile or persistent-cache load

@@ -179,7 +179,7 @@ prog = next(reversed(batch_jax._PROGRAMS.values()))
 res["hlo_gathers"] = [ln.strip() for ln in prog.run.as_text().splitlines()
                       if " gather(" in ln]
 res["draws_tw"] = batch_jax._draw_tables(
-    _pack_bank(draws, 0.0), np.array([0, 1]),
+    batch_jax._draw_counts(_pack_bank(draws, 0.0)), np.array([0, 1]),
     np.full(2, batch_jax._TRUST_FIXED_Q, np.int32), np.zeros(2),
     np.arange(2)).shape[1]
 res["adaptive"] = case(ragged, 120000.0, adaptive=AD)

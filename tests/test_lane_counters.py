@@ -7,9 +7,9 @@ it saw as JSON; the tests read that.  Cases: lanes that all share one
 trace and period (in lockstep), the same lanes mixed with others, one
 at a time, in chunks smaller than the lane count, and sharded over four
 virtual devices; an identical call
-twice, the process's compiled programs cleared between (so the persistent
-cache serves the second compile); one ``evaluate_strategies`` call under
-the profiler, compiling cold.
+twice, the process's compiled programs and banks cleared between (so the
+persistent cache serves the second compile); one ``evaluate_strategies``
+call under the profiler, compiling cold and packing its bank anew.
 """
 
 import json
@@ -33,7 +33,7 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 from jax.profiler import ProfileData
 
 from repro.core import batch_jax
-from repro.core.batch import _pack_bank, simulate_lanes, trust_code
+from repro.core.batch import simulate_lanes, trust_code
 from repro.core.batch_jax import run_lanes_jax
 from repro.core.policies import Strategy
 from repro.core.simulator import ThresholdTrust
@@ -57,13 +57,14 @@ def seeds(tr):
 def lanes(tr, periods, chunk=None, cold=False):
     if cold:        # compile again: no program of this process serves it
         batch_jax._PROGRAMS.clear()
+        batch_jax._BANKS.clear()
     tr = np.asarray(tr)
     n = tr.size
     kind, param = trust_code(TRUST)
     reg = MetricsRegistry()
     prev = set_registry(reg)
     try:
-        out = run_lanes_jax(_pack_bank(traces, 0.0), PLAT, TIME_BASE, tr,
+        out = run_lanes_jax(traces, PLAT, TIME_BASE, tr,
                             np.asarray(periods), np.full(n, kind, np.int32),
                             np.full(n, param), np.zeros(n), seeds(tr), CP,
                             chunk=chunk)
@@ -89,6 +90,7 @@ res["numpy"] = simulate_lanes(
     seeds=seeds(MIXED[0]), backend="numpy").tolist()
 
 batch_jax._PROGRAMS.clear()    # the compile's spans are among those seen
+batch_jax._BANKS.clear()       # and so are the bank's packing and put
 reg = MetricsRegistry()
 prev = set_registry(reg)
 opts = jax.profiler.ProfileOptions()
@@ -114,10 +116,10 @@ print("LANE-COUNTERS " + json.dumps(res))
 """
 
 # The spans on the path of one evaluate_strategies call on the jax engine.
-SPANS = {"runner.gather_s", "lanes.pack_s", "jax.draw_tables_s",
-         "jax.bank_put_s", "jax.init_chunk_s", "jax.lower_s",
-         "jax.xla_compile_s", "jax.dispatch_s", "jax.fetch_s",
-         "runner.collect_s"}
+SPANS = {"runner.gather_s", "jax.bank_check_s", "lanes.pack_s",
+         "jax.draw_tables_s", "jax.bank_put_s", "jax.init_chunk_s",
+         "jax.lower_s", "jax.xla_compile_s", "jax.dispatch_s",
+         "jax.fetch_s", "runner.collect_s"}
 
 
 @pytest.fixture(scope="module")
