@@ -1,18 +1,21 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the reference, and the result line.  ``bench/run.py`` is the command.
 
-Set-up: JAX with float64 on and the persistent compilation cache at
-``<checkout>/.bench_cache/jax``; the device check; the trace bank, one
-fixed pool drawn from the configuration's ``bank_seed`` by
-``bench/bankgen.py`` (kept in ``<checkout>/.bench_cache/banks`` after its
-first run) and put in an order drawn from the seed; one warm-up sweep on
-the unshifted grid, with the cell's own shapes.  The window: sweeps back to back, each one ``evaluate_strategies(..., engine="jax")`` call with
-a fresh in-memory ``EvalCache``, until ``--seconds`` have passed; a sweep
-started before the end is completed.  With ``--trace 1`` the profiler
-records the first sweeps of the window, at least ``trace_min_s`` seconds of
-them.  After the window: the device's peak memory, the trace's reduction,
-then every lane and answer of the window against the reference
-(``compare``).
+The configuration's semantics (``bench/semantics/``) says what its
+numbers mean: the check of its fields, the bank, the strategies, the
+reference and the lane loop's bytes.  Set-up: JAX with float64 on and the
+persistent compilation cache at ``<checkout>/.bench_cache/jax``; the
+device check; the trace bank, one fixed pool drawn from the
+configuration's ``bank_seed`` (kept in ``<checkout>/.bench_cache/banks``
+after its first run) and put in an order drawn from the seed; one warm-up
+sweep on the unshifted grid, with the cell's own shapes.  The window:
+sweeps back to back, each one ``evaluate_strategies(..., engine="jax")``
+call with a fresh in-memory ``EvalCache``, until ``--seconds`` have
+passed; a sweep started before the end is completed.  With ``--trace 1``
+the profiler records the first sweeps of the window, at least
+``trace_min_s`` seconds of them.  After the window: the device's peak
+memory, the trace's reduction, then every lane and answer of the window
+against the reference (``compare``).
 """
 
 from __future__ import annotations
@@ -30,8 +33,6 @@ import traceback
 
 import numpy as np
 
-import bankgen
-import lanebytes
 import reference
 import sweeps
 import xplane
@@ -43,11 +44,6 @@ BANK_DIR = os.path.join(ROOT, ".bench_cache", "banks")
 WORK_DIR = os.path.join(ROOT, ".bench_work")
 LANE_LOOP = "jit__loop"        # the lane loop's program name in the trace
 SPAN = "sweep"                 # the harness's span around each call
-
-# Scenario fields the reference does not model, with the one value it does.
-_PLAIN = {"window": 0.0, "predictor": None, "silent_mu_ind": None,
-          "n_verify": 0, "verify_cost": 0.0, "keep_ckpts": 1,
-          "false_pred_dist": None}
 
 
 class NoChip(RuntimeError):
@@ -75,11 +71,14 @@ def load_cell(bench: dict, name: str):
     return cell, cfg, mix
 
 
-def check_config(cfg: dict) -> None:
-    for key, plain in _PLAIN.items():
-        if cfg.get(key, plain) != plain:
-            raise ValueError(f"the reference does not model {key}="
-                             f"{cfg[key]!r}")
+def semantics(cfg: dict):
+    """The module ``bench/semantics/<name>.py`` of the configuration's
+    ``semantics`` (``"plain"`` where absent)."""
+    name = cfg.get("semantics", "plain")
+    if not (isinstance(name, str) and name.isidentifier()
+            and importlib.util.find_spec("semantics." + name)):
+        raise ValueError(f"unknown semantics {name!r}")
+    return importlib.import_module("semantics." + name)
 
 
 def cell_metrics(bench: dict, trace: bool) -> list[dict]:
@@ -131,18 +130,19 @@ def check_devices(devices, chips: int, peaks: dict) -> dict:
     return peaks["devices"][dev.device_kind]
 
 
-def pool_bank(cfg: dict):
-    """The configuration's trace pool (``bankgen.make_bank`` of its
+def pool_bank(cfg: dict, sem):
+    """The configuration's trace pool (its semantics' ``bank`` of its
     ``bank_seed``), kept under ``BANK_DIR`` keyed by the configuration and
     the generator's source, so that only a checkout's first run draws it."""
     key = hashlib.sha256(json.dumps(cfg, sort_keys=True).encode())
-    with open(os.path.join(BENCH, "bankgen.py"), "rb") as fh:
-        key.update(fh.read())
+    for src in sem.BANK_SOURCES:
+        with open(os.path.join(BENCH, src), "rb") as fh:
+            key.update(fh.read())
     path = os.path.join(BANK_DIR, f"{cfg['name']}-{key.hexdigest()[:16]}.npz")
     if os.path.exists(path):
         with np.load(path) as z:
             return z["times"], z["kinds"], z["n_events"]
-    times, kinds, n_events = bankgen.make_bank(cfg, int(cfg["bank_seed"]))
+    times, kinds, n_events = sem.bank(cfg, int(cfg["bank_seed"]))
     os.makedirs(BANK_DIR, exist_ok=True)
     tmp = f"{path}.{os.getpid()}.tmp.npz"
     np.savez(tmp, times=times, kinds=kinds, n_events=n_events)
@@ -166,32 +166,26 @@ class Planner:
     """Sends sweeps to the program and keeps what it returns."""
 
     def __init__(self, jax, cfg: dict, mix: dict, seed: int):
-        from repro.core.simulator import ThresholdTrust
-        from repro.core.traces import EventTrace
         from repro.core.waste import Platform
 
         self.jax, self.mix, self.seed = jax, mix, seed
-        self.sc = sc = bankgen.scenario(cfg)
+        self.sem = sem = semantics(cfg)
+        self.sc = sc = sem.scenario(cfg)
         sweeps.check_grid(mix, sc)
-        times, kinds, n_events = pool_bank(cfg)
+        times, kinds, n_events = pool_bank(cfg, sem)
         order = trace_order(seed, times.shape[0])
         self.times, self.kinds = times[order], kinds[order]
         self.n_events = n_events[order]
-        horizon = sc["horizon"] - cfg["start"]
-        self.traces = [EventTrace(self.times[i], self.kinds[i], horizon)
-                       for i in range(self.times.shape[0])]
+        self.traces = sem.traces(cfg, sc, self.times, self.kinds)
         self.platform = Platform(mu=sc["mu"], c=sc["c"], d=sc["d"],
                                  r=sc["r"])
-        self.trust = ThresholdTrust(sc["beta_lim"])
 
     def sweep(self, k: int) -> dict:
-        from repro.core.policies import Strategy
         from repro.experiments.runner import EvalCache, evaluate_strategies
         from repro.obs.metrics import MetricsRegistry, set_registry
 
         periods = sweeps.periods(self.mix, self.sc, self.seed, k)
-        strategies = [Strategy(f"T={p!r}", float(p), self.trust)
-                      for p in periods]
+        strategies = self.sem.strategies(self.mix, self.sc, periods)
         cache, reg = EvalCache(), MetricsRegistry()
         prev = set_registry(reg)
         means, err = None, None
@@ -266,15 +260,16 @@ def _seq_mean(values) -> float:
 
 
 def reference_lanes(planner: Planner, recs: list, ftype=float):
-    """(sweeps, periods, traces) makespans of the reference, in ``ftype``,
-    for every lane of ``recs``."""
+    """(sweeps, periods, traces) makespans of the configuration's reference,
+    in ``ftype``, for every lane of ``recs``."""
+    sem, sc, n = planner.sem, planner.sc, planner.times.shape[0]
     periods = np.concatenate([r["periods"] for r in recs])
-    n_lanes = periods.size * planner.times.shape[0]
-    workers = reference.default_workers() if n_lanes >= 2000 else 1
-    out = reference.makespans(planner.times, planner.kinds, planner.n_events,
-                              periods, planner.sc["beta_lim"], planner.sc,
-                              ftype=ftype, workers=workers)
-    return out.reshape(len(recs), -1, planner.times.shape[0])
+    workers = reference.default_workers() if periods.size * n >= 2000 else 1
+    out = importlib.import_module(sem.REFERENCE).makespans(
+        planner.times, planner.kinds, planner.n_events, periods, sc,
+        sem.settings(planner.mix, sc), planner.seed, ftype=ftype,
+        workers=workers)
+    return out.reshape(len(recs), -1, n)
 
 
 def compare(recs: list, lanes: list, ref: np.ndarray, program=None) -> dict:
@@ -326,7 +321,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
     """One run; returns the result object (the last line of stdout)."""
     cell, cfg0, mix0 = load_cell(bench, name)
     cfg, mix = cfg or cfg0, mix or mix0
-    check_config(cfg)
+    semantics(cfg).check(cfg, mix)
     sweeps.check_mix(mix)
     jax = start_jax()
     devices = jax.devices()
@@ -368,7 +363,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
             lt = np.tile(np.arange(planner.times.shape[0]),
                          len(ok[0]["strategies"]))
             ids = {id(r) for r in traced}
-            traced_bytes = sum(lanebytes.lane_loop_bytes(
+            traced_bytes = sum(planner.sem.lane_bytes(
                 planner.times, lt, la.reshape(-1))
                 for r, la in zip(ok, lanes) if id(r) in ids)
 

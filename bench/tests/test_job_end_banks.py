@@ -23,7 +23,6 @@ from conftest import BENCH
 
 @pytest.mark.parametrize("config", ["paper-exp-2p16", "paper-w07-2p19"])
 def test_job_end_is_the_scalar_decision_on_every_lane(config):
-    from repro.core.policies import Strategy
     from repro.experiments.runner import EvalCache, evaluate_strategies
     from repro.obs.metrics import MetricsRegistry, set_registry
 
@@ -33,8 +32,7 @@ def test_job_end_is_the_scalar_decision_on_every_lane(config):
     planner = harness.Planner(jax, cfg, mix, 0)
     sc = planner.sc
     periods = sweeps.periods(mix, sc, 0, sweeps.WARMUP)
-    strategies = [Strategy(f"T={p!r}", float(p), planner.trust)
-                  for p in periods]
+    strategies = planner.sem.strategies(mix, sc, periods)
     cache, reg = EvalCache(), MetricsRegistry()
     prev = set_registry(reg)
     try:

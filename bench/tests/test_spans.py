@@ -109,10 +109,42 @@ def cache_every_compile():
     jax.config.update(key, old)
 
 
+def _traced_window(planner, made, trace_dir):
+    """A traced window of ``planner``, each sweep's registry (the last
+    ones ``made``) kept on its record; the trace's host event names."""
+    first = len(made)
+    _, recs, _ = harness.window(planner, 0.01, trace_dir, 0.0)
+    for rec, reg in zip(recs, made[first:]):
+        rec.update(timers=reg.timers, counters=reg.counters)
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    planes = xplane.load(files[0])
+    names = {ev[0] for pname, lines in planes
+             if pname.startswith("/host:") for _, evs in lines for ev in evs}
+    return recs, planes, names
+
+
+def _read(recs, planes):
+    got = {m: harness.reader(m)(harness.Run(sweeps=recs, planes=planes))
+           for m in READERS}
+    # The CPU has no device plane that xplane reads.
+    assert got.pop("device.idle_unspanned_pct") is None
+    assert got["lane_loop.iters_per_sweep"] > 0
+    assert 0.0 < got["lane_loop.lockstep_pct"] <= 100.0
+    return got
+
+
 def test_readers_on_a_traced_cpu_window(monkeypatch, tmp_path,
                                         cache_every_compile):
-    """A window of the w07 cell at a small size under the profiler, each
-    sweep's registry kept on its record, as the readers expect."""
+    """Windows of the w07 cell at a small size under the profiler, each
+    sweep's registry kept on its record, as the readers expect.
+
+    A warm window reuses the lane loop's program and the bank on the
+    device: it lowers, compiles and packs nothing, and the readers of those
+    spans and of cache misses read nothing.  With both dropped from the
+    process, a sweep lowers again, loads the program from the persistent
+    cache the warm-up wrote, and packs and puts the bank."""
+    import repro.core.batch_jax as bj
     import repro.obs.metrics as metrics
 
     made = []
@@ -129,20 +161,32 @@ def test_readers_on_a_traced_cpu_window(monkeypatch, tmp_path,
                               dict(mix, n_periods=8), SEED)
     planner.sweep(0)                          # compiles outside the window
     monkeypatch.setattr(metrics, "MetricsRegistry", Kept)
-    _, recs, _ = harness.window(planner, 0.01, str(tmp_path), 0.0)
-    for rec, reg in zip(recs, made):
-        rec.update(timers=reg.timers, counters=reg.counters)
-    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
-    run = harness.Run(sweeps=recs, planes=xplane.load(files[0]))
-    got = {m: harness.reader(m)(run) for m in READERS}
-    # The CPU has no device plane that xplane reads.
-    assert got.pop("device.idle_unspanned_pct") is None
+
+    recs, planes, names = _traced_window(planner, made,
+                                         str(tmp_path / "warm"))
+    for rec in recs:
+        assert rec["counters"]["jax.exec_reuses"] == 1
+        assert rec["counters"]["jax.bank_reuses"] == 1
+        spanned = set(rec["timers"]) - {"jax.compile_s", "jax.run_s"}
+        assert "jax.fetch_s" in spanned and spanned <= names
+    got = _read(recs, planes)
+    assert got["compile.lower_s"] is None
+    assert got["compile.cache_misses"] is None
+    assert got["host.program_prep_s"] is None
+    assert not {"jax.lower_s", "lanes.pack_s", "jax.bank_put_s"} & names
+
+    with bj._PROGRAMS_LOCK:
+        bj._PROGRAMS.clear()
+    with bj._BANKS_LOCK:
+        bj._BANKS.clear()
+    recs, planes, names = _traced_window(planner, made,
+                                         str(tmp_path / "cold"))
+    first = recs[0]                           # a later sweep would reuse
+    assert "jax.exec_reuses" not in first["counters"]
+    assert "jax.bank_reuses" not in first["counters"]
+    got = _read([first], planes)
     assert all(v is not None for v in got.values()), got
-    assert 0.0 < got["compile.lower_s"] <= recs[0]["compile_s"]
-    assert 0.0 < got["host.program_prep_s"] < recs[0]["wall_s"]
-    assert got["compile.cache_misses"] == 0     # compiled before the window
-    assert got["lane_loop.iters_per_sweep"] > 0
-    assert 0.0 < got["lane_loop.lockstep_pct"] <= 100.0
-    names = {ev[0] for pname, lines in run.planes
-             if pname.startswith("/host:") for _, evs in lines for ev in evs}
+    assert 0.0 < got["compile.lower_s"] <= first["compile_s"]
+    assert 0.0 < got["host.program_prep_s"] < first["wall_s"]
+    assert got["compile.cache_misses"] == 0     # the warm-up wrote it
     assert set(spans.HOST_PREP) | {"jax.lower_s", "jax.fetch_s"} <= names
